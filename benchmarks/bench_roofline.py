@@ -179,7 +179,7 @@ def _kernel_roofline(rows, quick: bool):
     pt = jnp.arange(1, 1 + b * maxp, dtype=jnp.int32).reshape(b, maxp)
     lens = jnp.full((b,), page * maxp, jnp.int32)
     if on_tpu:
-        f = jax.jit(lambda *xs: _pl(*xs))
+        f = jax.jit(lambda *xs: _pl(*xs, interpret=False))
     else:
         f = jax.jit(lambda *xs: ref.paged_decode_attention_ref(*xs))
     t = best_s(f, q, kp, vp, pt, lens)
@@ -199,7 +199,8 @@ def _kernel_roofline(rows, quick: bool):
     kf = jnp.asarray(rng.randn(bq, s, hq, dh), jnp.float32)
     vf = jnp.asarray(rng.randn(bq, s, hq, dh), jnp.float32)
     if on_tpu:
-        g = jax.jit(lambda *xs: flash_attention_pallas(*xs, causal=True))
+        g = jax.jit(lambda *xs: flash_attention_pallas(
+            *xs, causal=True, interpret=False))
     else:
         g = jax.jit(lambda *xs: ref.flash_attention_ref(*xs, causal=True))
     t = best_s(g, qf, kf, vf)

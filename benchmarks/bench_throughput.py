@@ -144,12 +144,15 @@ def _bandwidth_columns(rows, quick: bool):
     on the link, so ``circular_int8`` strictly beating ``circular`` is
     the wire-speed acceptance.  Needs real stage boundaries (payloads
     only cross between stages), so it runs a 2-stage pipe on two fake
-    host devices in a fresh interpreter, whatever this host has."""
+    host devices in a fresh interpreter, whatever this host has.  The
+    child runs on the CPU explicitly: its numbers come from the virtual
+    clock, and an accelerator belongs to the parent that already holds it."""
     import json
     import os
     import subprocess
     import sys
-    env = dict(os.environ, BW_QUICK="1" if quick else "0")
+    env = dict(os.environ, BW_QUICK="1" if quick else "0",
+               JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.pathsep.join(filter(None, [
         os.path.join(os.path.dirname(__file__), "..", "src"),
         env.get("PYTHONPATH")]))

@@ -50,6 +50,8 @@ def main() -> None:
     if args.json is None:
         args.json = "" if args.only else "BENCH_throughput.json"
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_attention_schemes, bench_batch_curve,
                             bench_cost_model, bench_offload, bench_roofline,
                             bench_throughput)

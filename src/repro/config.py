@@ -260,6 +260,21 @@ def list_archs() -> list:
     return sorted(_REGISTRY)
 
 
+_WIDTH_FIELDS = ("d_model", "num_heads", "num_kv_heads", "head_dim", "d_ff",
+                 "vocab_size", "d_rnn", "block_pattern", "window_size", "moe")
+
+
+def at_published_widths(cfg: ModelConfig) -> bool:
+    """True when ``cfg`` keeps every width of the registered arch it is
+    named after — only its depth may be cut.  ``reduced_config`` renames
+    its result, so the CPU-sized configs are never at published widths."""
+    try:
+        ref = get_arch(cfg.name)
+    except KeyError:
+        return False
+    return all(getattr(cfg, f) == getattr(ref, f) for f in _WIDTH_FIELDS)
+
+
 def reduced_config(cfg: ModelConfig, *, num_layers: int = 0,
                    d_model: int = 64, vocab: int = 128) -> ModelConfig:
     """A tiny config of the same family for CPU smoke tests.

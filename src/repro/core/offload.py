@@ -13,9 +13,9 @@ host-DMA path (HBM ↔ host DRAM).  The :class:`DoubleBufferOffloader` below
 implements the *schedule* (pool parity, swap-out of the departing microbatch
 overlapped with swap-in of the arriving one).  In the default async mode
 (``async_swap=True``) the swap-out stores the *enqueued* jax copy — a
-lazily-materialised device array (routed to ``pinned_host`` when
-:func:`place_host_store` armed a host sharding on TPU) — so the D2H of
-buffer A overlaps the next tick's jit computing into buffer B; nothing
+lazily-materialised device array (routed to ``pinned_host`` on TPU, in
+the pool's own sharding) — so the D2H of buffer A overlaps the next tick's
+jit computing into buffer B; nothing
 blocks until :meth:`DoubleBufferOffloader.settle` (drain/reshard) or the
 value is consumed by a swap-in.  ``async_swap=False`` keeps the old
 blocking numpy round-trip for debugging and bit-exactness A/B runs — the
@@ -154,7 +154,6 @@ class DoubleBufferOffloader:
         self.resident: Dict[int, Optional[int]] = {0: None, 1: None}
         # mb -> per-layer {"k","v"} store, or a Future of it (async mode)
         self._host: Dict[int, Union[List[dict], Future]] = {}
-        self._host_sharding = None        # armed by place_host_store (TPU)
         self.swap_count = 0
         self.bytes_swapped = 0
         # flight recorder (set by the backend when tracing is on): swap
@@ -210,8 +209,8 @@ class DoubleBufferOffloader:
                 if isinstance(c, dict) and "k_pages" in c:
                     axis = 1 if part == "scan" else 0
                     if incoming is not None:
-                        k_new = jnp.asarray(incoming[li]["k"])
-                        v_new = jnp.asarray(incoming[li]["v"])
+                        k_new = _on_device(incoming[li]["k"])
+                        v_new = _on_device(incoming[li]["v"])
                         c = {**c,
                              "k_pages": jax.lax.dynamic_update_slice_in_dim(
                                  c["k_pages"], k_new.astype(c["k_pages"].dtype),
@@ -244,11 +243,12 @@ class DoubleBufferOffloader:
             k = _snapshot_slice(c["k_pages"], sl.start, sl.stop, axis)
             v = _snapshot_slice(c["v_pages"], sl.start, sl.stop, axis)
             if self.async_swap:
-                if self._host_sharding is not None:
+                host = _pinned_host(k)
+                if host is not None:
                     # TPU: enqueue the D2H DMA toward pinned_host now;
                     # it lands while the next tick jit runs
-                    k = jax.device_put(k, self._host_sharding)
-                    v = jax.device_put(v, self._host_sharding)
+                    k = jax.device_put(k, host)
+                    v = jax.device_put(v, host)
                 store.append({"k": k, "v": v})
             else:
                 # repro-audit: allow(host-sync, offload-sync) — async_swap=False opt-out: the blocking numpy round-trip, kept for debugging and A/B bit-exactness runs
@@ -284,30 +284,17 @@ def host_memory_available() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def pool_shardings(mesh, spec, *, host: bool):
-    """NamedSharding for a KV pool buffer; ``host=True`` places it in
-    pinned host memory (the paper's CPU-RAM side of the PCIe swap)."""
-    if host and host_memory_available():
-        return jax.sharding.NamedSharding(mesh, spec,
-                                          memory_kind="pinned_host")
-    # None = the backend's default memory kind (CPU backends reject an
-    # explicit "device" kind; TPU default is HBM, which is what we want)
-    return jax.sharding.NamedSharding(mesh, spec)
-
-
-def place_host_store(offloader: "DoubleBufferOffloader", mesh, spec):
-    """Move the offloader's host store to pinned host buffers on TPU and
-    arm the sharding so future async swap-outs enqueue device→pinned_host
-    DMAs directly.  On CPU this is a no-op (the numpy / jax store *is*
-    host memory)."""
+def _pinned_host(x: jax.Array):
+    """``x``'s own sharding in pinned host memory, or None where the
+    backend has no host memory space."""
     if not host_memory_available():
-        return offloader
-    sh = pool_shardings(mesh, spec, host=True)
-    offloader._host_sharding = sh
-    offloader.settle()                    # resolve in-flight futures first
-    offloader._host = {
-        mb: [{k: jax.device_put(jnp.asarray(v), sh) for k, v in layer.items()}
-             for layer in layers]
-        for mb, layers in offloader._host.items()
-    }
-    return offloader
+        return None
+    return x.sharding.with_memory_kind("pinned_host")
+
+
+def _on_device(x):
+    """A host-store entry as a device-memory array: the swap-in splices it
+    into a pool that lives in device memory."""
+    if isinstance(x, jax.Array) and x.sharding.memory_kind == "pinned_host":
+        return jax.device_put(x, x.sharding.with_memory_kind("device"))
+    return jnp.asarray(x)

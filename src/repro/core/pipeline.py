@@ -33,6 +33,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.config import ModelConfig
 from repro.models import embedding as embed_lib
@@ -41,21 +42,12 @@ from repro.models.common import Runtime, make_layer_plan, rms_norm
 
 
 def _shard_map(f, *, mesh, axis_names, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new-style (axis_names /
-    check_vma) when present, else ``jax.experimental.shard_map`` with the
-    complementary ``auto`` axis set (manual over ``axis_names`` only)."""
-    if hasattr(jax, "shard_map"):
-        # repro-audit: allow(retrace-jit) — trace-time only: callers wrap the tick in one outer jax.jit, so this wrapper is built once per compile, never per tick
-        return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    # the experimental API cannot partial-auto this body on older jax
-    # (axis_index lowers to an unsupported PartitionId under SPMD
-    # partitioning); every spec only references the manual axes, so run
-    # fully manual — the remaining axes are replicated either way
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` manual over ``axis_names`` only: data/tensor axes
+    inside each stage stay automatic."""
+    # repro-audit: allow(retrace-jit) — trace-time only: callers wrap the tick in one outer jax.jit, so this wrapper is built once per compile, never per tick
+    return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
+                         in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)
 
 
 def _wire_permute(y, n_stages: int, wire_dtype: str):
@@ -110,6 +102,41 @@ def split_layers(cfg: ModelConfig, n_stages: int):
             f"{cfg.name}: {plan.n_periods} periods cannot fill {n_stages} "
             "pipeline stages")
     return pps, leftover
+
+
+def stage_mesh(n_stages: int):
+    """The ``pod`` mesh over the first ``n_stages`` devices — one pipeline
+    stage per device."""
+    devs = jax.devices()
+    if len(devs) < n_stages:
+        raise RuntimeError(
+            f"pipelined backend needs >= {n_stages} devices for the "
+            f"pod axis, have {len(devs)} — set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_stages} "
+            "before initialising jax, or reduce --stages")
+    return jax.sharding.Mesh(np.array(devs[:n_stages]), ("pod",))
+
+
+def stage_shardings(cfg: ModelConfig, mesh, n_stages: int):
+    """Per-stage placement of a params or cache tree, as a function from
+    the tree (arrays or shapes) to its tree of shardings.
+
+    Leaves under ``"scan"`` carry the period stack on axis 0; they are
+    split over ``"pod"``, so stage ``s`` holds periods ``[s·pps,
+    (s+1)·pps)`` — exactly the slice the tick functions reshape out of the
+    stack, so no weight or cache byte ever crosses a stage boundary.
+    Everything else (embeddings, final norm, tail layers) is replicated.
+    Leftover periods (``n_periods % n_stages``) leave the stack uneven; it
+    then stays replicated."""
+    P = jax.sharding.PartitionSpec
+    _, leftover = split_layers(cfg, n_stages)
+    rep = jax.sharding.NamedSharding(mesh, P())
+    split = rep if leftover else jax.sharding.NamedSharding(mesh, P("pod"))
+
+    def place(tree: dict) -> dict:
+        return {k: jax.tree.map(lambda _: split if k == "scan" else rep, v)
+                for k, v in tree.items()}
+    return place
 
 
 def split_scan_params(params: dict, cfg: ModelConfig, n_stages: int):

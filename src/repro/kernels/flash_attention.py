@@ -18,11 +18,14 @@ The pure-jnp oracle is ``repro.models.attention.flash_attention``
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
@@ -107,7 +110,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, ml_ref, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int = 0,
                            q_blk: int = 128, kv_blk: int = 128,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """q (B,Sq,H,Dh); k,v (B,Skv,Hk,Dh) -> (B,Sq,H,Dh)."""
     b, sq, h, dh = q.shape
     skv, hk = k.shape[1], k.shape[2]
@@ -148,7 +151,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((g * q_blk, dh), jnp.float32),
             pltpu.VMEM((g * q_blk, 2), jnp.float32),   # fused (m, l) stats
         ],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(qr, kr, vr)
 
     out = out[:, :, :sq].reshape(b, hk, g, sq, dh).reshape(b, h, sq, dh)

@@ -1,13 +1,17 @@
 """jit'd dispatch layer over the Pallas kernels.
 
 Routes to the Pallas implementation when the shape is TPU-tileable, and to
-the pure-jnp oracle otherwise.  On non-TPU backends the kernels execute in
-``interpret=True`` mode (Python evaluation of the kernel body) — numerically
-identical, structurally the same program.
+the pure-jnp oracle otherwise.  The route depends on the shape only: on a
+TPU the kernels run compiled, elsewhere in interpret mode (Python evaluation
+of the kernel body) — numerically identical, structurally the same program
+(``repro.kernels.default_interpret``).
 
 The routing predicate is conservative: Pallas requires the head dim to be a
 multiple of the 128-lane register width for MXU efficiency (64 is accepted:
-it packs two heads per register row on v5e).
+it packs two heads per register row on v5e).  The paged kernel streams one
+KV head's ``(page_size, Dh)`` column slab of the ``(page, Hk·Dh)`` pool
+rows, so its lane dimension must be a multiple of 128 unless the pool holds
+a single KV head.
 """
 
 from __future__ import annotations
@@ -20,10 +24,6 @@ from repro.kernels.flash_attention import (flash_attention_pallas,
                                            tuned_flash_blocks)
 from repro.kernels.paged_attention import paged_decode_attention as _paged_pl
 from repro.kernels.rglru_scan import rglru_scan_pallas
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _aligned(*dims: int) -> bool:
@@ -43,8 +43,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         q_blk = max(8, min(q_chunk, tq))
         kv_blk = max(8, min(kv_chunk, tkv))
         return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      q_blk=q_blk, kv_blk=kv_blk,
-                                      interpret=_interpret())
+                                      q_blk=q_blk, kv_blk=kv_blk)
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window,
                                    q_offset=q_offset, q_chunk=q_chunk,
                                    kv_chunk=kv_chunk, scheme=scheme)
@@ -62,11 +61,10 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
     """``pages_per_block=0`` autotunes the per-grid-step page count from
     the ``(page_size, Dh, G)`` shape (see ``tuned_pages_per_block``)."""
     dh = q.shape[-1]
-    page_size = k_pages.shape[1]
-    if _aligned(dh) and page_size % 8 == 0:
+    page_size, hk = k_pages.shape[1], k_pages.shape[2]
+    if _aligned(dh) and (dh % 128 == 0 or hk == 1) and page_size % 8 == 0:
         return _paged_pl(q, k_pages, v_pages, page_table, seq_lens,
-                         window=window, pages_per_block=pages_per_block,
-                         interpret=_interpret())
+                         window=window, pages_per_block=pages_per_block)
     return ref.paged_decode_attention_ref(q, k_pages, v_pages, page_table,
                                           seq_lens, window=window)
 
@@ -76,5 +74,5 @@ def rglru_scan(a, b, h0=None):
     if h0 is None:
         h0 = jnp.zeros((bsz, dr), jnp.float32)
     if dr % 128 == 0 and s >= 8:
-        return rglru_scan_pallas(a, b, h0, interpret=_interpret())
+        return rglru_scan_pallas(a, b, h0)
     return ref.rglru_scan_ref(a, b, h0)

@@ -10,6 +10,12 @@ materialising the contiguous KV.
 
 Layout/tuning (FlashInfer-style multi-page streaming):
 
+* The pool is viewed as ``(n_pages, page_size, Hk·Dh)`` (a free reshape)
+  and each KV block is one head's ``(page_size, Dh)`` slab of a page.  Its
+  last two block dimensions are then tile-legal on the TPU (``page_size``
+  a multiple of 8, ``Dh`` a multiple of 128); a ``(page_size, 1, Dh)``
+  block over the 4-D pool is not, since one head of ``Hk`` is neither a
+  multiple of 8 nor the whole axis.
 * Grid = ``(B, Hk, n_blocks)`` — one grid dimension per KV head so GQA
   groups never share a softmax scratch, and the innermost dimension walks
   *blocks* of ``pages_per_block`` pages.  Each grid step DMAs
@@ -42,11 +48,14 @@ Oracle: ``repro.kernels.ref.paged_decode_attention_ref``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
@@ -106,11 +115,11 @@ def _paged_kernel(pt_ref, len_ref, q_ref, *refs, page_size: int,
     def _compute():
         q = q_ref[0, 0]                        # (G, Dh)
         if ppb == 1:
-            k = ks[0][0, :, 0]                 # (page_size, Dh)
-            v = vs[0][0, :, 0]
+            k = ks[0][0]                       # (page_size, Dh)
+            v = vs[0][0]
         else:
-            k = jnp.concatenate([kr[0, :, 0] for kr in ks], axis=0)
-            v = jnp.concatenate([vr[0, :, 0] for vr in vs], axis=0)
+            k = jnp.concatenate([kr[0] for kr in ks], axis=0)
+            v = jnp.concatenate([vr[0] for vr in vs], axis=0)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / (q_ref.shape[-1] ** 0.5))          # (G, ppb·page)
@@ -146,7 +155,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array,
                            seq_lens: jax.Array, *, window: int = 0,
                            pages_per_block: int = 0,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Decode attention over a paged KV pool.
 
     q          (B, H, Dh)         current-token queries
@@ -178,10 +187,10 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
             n_pages = (len_ref[bi] + page_size - 1) // page_size
             last = jnp.minimum(jnp.maximum(n_pages - 1, 0), max_pages - 1)
             slot = jnp.minimum(blki * ppb + j, last)
-            return (pt_ref[bi, slot], 0, hi, 0)
+            return (pt_ref[bi, slot], 0, hi)
         return index_map
 
-    kv_specs = [pl.BlockSpec((1, page_size, 1, dh), _kv_map(j))
+    kv_specs = [pl.BlockSpec((1, page_size, dh), _kv_map(j))
                 for j in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -207,7 +216,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(pt, seq_lens.astype(jnp.int32), qr,
-      *([k_pages] * ppb), *([v_pages] * ppb))
+      *([k_pages.reshape(n_pool, page_size, hk * dh)] * ppb),
+      *([v_pages.reshape(n_pool, page_size, hk * dh)] * ppb))
     return out.reshape(b, h, dh)

@@ -12,11 +12,14 @@ Oracle: ``repro.models.rglru.rglru_scan`` (associative_scan form).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import default_interpret
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, s_blk: int):
@@ -38,7 +41,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, s_blk: int):
 @functools.partial(jax.jit, static_argnames=("s_blk", "d_blk", "interpret"))
 def rglru_scan_pallas(a: jax.Array, b: jax.Array, h0: jax.Array, *,
                       s_blk: int = 256, d_blk: int = 512,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """a, b (B, S, Dr) f32; h0 (B, Dr) f32 -> all h_t (B, S, Dr) f32."""
     bsz, s, dr = a.shape
     s_blk = min(s_blk, s)
@@ -63,6 +66,6 @@ def rglru_scan_pallas(a: jax.Array, b: jax.Array, h0: jax.Array, *,
                                lambda bi, di, si: (bi, si, di)),
         out_shape=jax.ShapeDtypeStruct((bsz, s + ps, dr + pd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, d_blk), jnp.float32)],
-        interpret=interpret,
+        interpret=default_interpret() if interpret is None else interpret,
     )(a.astype(jnp.float32), b.astype(jnp.float32), h0.astype(jnp.float32))
     return out[:, :s, :dr]

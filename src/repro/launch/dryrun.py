@@ -381,8 +381,6 @@ def run_cell(arch: str, shape_name: str, mesh_name: str,
             t2 = time.time()
             ma = compiled.memory_analysis()
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):   # older jax: list per device
-                ca = ca[0] if ca else {}
             txt = compiled.as_text()
         n_dev = mesh.devices.size
         coll = collective_bytes(txt)

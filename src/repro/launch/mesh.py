@@ -21,11 +21,20 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_for(devices: int, *, model_parallel: int = 16):
     """Elastic variant: the largest (data, model) mesh for ``devices``."""
     from repro.distributed.elastic import ElasticPlanner
     plan = ElasticPlanner(model_parallel=model_parallel).plan(devices)
-    return jax.make_mesh(plan.shape, plan.axes)
+    return _auto_mesh(plan.shape, plan.axes)
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the partitioner shards automatically.  ``jax.make_mesh``
+    otherwise types every axis Explicit, and the sharding rules of this
+    package (``with_sharding_constraint`` inside ``jit``) are written for
+    automatic axes."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)

@@ -317,12 +317,15 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.config import get_arch, reduced_config
     from repro.core.cost_model import PLATFORMS, min_throughput, \
         profit_per_hour
     from repro.core.scheduler import optimal_microbatches
     from repro.models import model as model_lib
-    from repro.models.common import Runtime
+    from repro.models.common import serving_runtime
     from repro.serving.kv_cache import PoolConfig
     from repro.serving.llm import LLM, EngineConfig
     from repro.serving.request import SamplingParams
@@ -367,13 +370,15 @@ def main() -> None:
     cfg = get_arch(args.arch)
     if not args.full_size:
         cfg = reduced_config(cfg)
-    rt = Runtime(param_dtype=jnp.float32, compute_dtype=jnp.float32)
+    rt = serving_runtime(cfg)
     print(f"arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
-          f"params={cfg.param_count()/1e6:.1f}M backend={args.backend}")
+          f"params={cfg.param_count()/1e6:.1f}M "
+          f"dtype={jnp.dtype(rt.param_dtype).name} backend={args.backend}")
 
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed), rt)
-
+    params = None                       # LLM builds them where they live
     if args.plan:
+        params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed),
+                                       rt)
         t_s = measure_stage_time(cfg, params, rt, args.stages)
         # planner latency input: the deployment plan's max ring-link
         # latency (the slowest link sets the bubble budget) beats a

@@ -54,6 +54,16 @@ class Runtime:
 DEFAULT_RUNTIME = Runtime()
 
 
+def serving_runtime(cfg: ModelConfig) -> Runtime:
+    """The dtype the served path uses when the caller gives no ``Runtime``:
+    bf16 at published widths (what fits a chip), fp32 for the reduced
+    CPU-sized configs (what the equivalence tests compare bitwise)."""
+    from repro.config import at_published_widths
+    if at_published_widths(cfg):
+        return DEFAULT_RUNTIME
+    return Runtime(param_dtype=jnp.float32, compute_dtype=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Layer plan: scan over pattern periods + unrolled tail
 # ---------------------------------------------------------------------------

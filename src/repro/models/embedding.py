@@ -44,8 +44,9 @@ def embed_patches(params: dict, patches: jax.Array, cfg: ModelConfig,
 def unembed(params: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """(..., D) -> (..., V) logits in fp32 (softcap applied if configured)."""
     table = params["tok"] if cfg.tie_embeddings else params["untok"]
-    logits = jnp.einsum("...d,vd->...v", x, table.astype(x.dtype))
-    logits = logits.astype(jnp.float32)
+    # accumulate in fp32: bf16 logits round 64k-way argmaxes into ties
+    logits = jnp.einsum("...d,vd->...v", x, table.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
     if cfg.logit_softcap > 0.0:
         logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
     return logits

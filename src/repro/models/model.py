@@ -147,12 +147,30 @@ _KIND_INIT = {
 }
 
 
-def _stack(trees: Sequence[Any]):
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def init_params(cfg: ModelConfig, key: jax.Array,
-                rt: Runtime = DEFAULT_RUNTIME) -> dict:
+                rt: Runtime = DEFAULT_RUNTIME, *, shardings=None) -> dict:
+    """Random parameters from ``key``.
+
+    One jit builds every leaf in place: the scanned periods come from a
+    vmap over per-period keys, so each stacked leaf is written once rather
+    than built layer by layer and then copied by a stack (a transient of
+    twice the weights).  ``shardings`` — a function from the parameter
+    shape tree to a sharding tree — places each leaf as it is made (the
+    pipelined backend's per-stage layout); None keeps the default
+    device."""
+    if shardings is None:
+        return _init_params_jit(cfg, rt)(key)
+    build = functools.partial(_init_params, cfg, rt=rt)
+    return jax.jit(build, out_shardings=shardings(
+        jax.eval_shape(build, key)))(key)
+
+
+@functools.lru_cache(maxsize=None)
+def _init_params_jit(cfg: ModelConfig, rt: Runtime):
+    return jax.jit(functools.partial(_init_params, cfg, rt=rt))
+
+
+def _init_params(cfg: ModelConfig, key: jax.Array, rt: Runtime) -> dict:
     kg = KeyGen(key)
     pd = rt.param_dtype
     plan = make_layer_plan(cfg.num_layers, cfg.block_pattern)
@@ -171,18 +189,43 @@ def init_params(cfg: ModelConfig, key: jax.Array,
         params["embed"]["untok"] = dense_init(
             kg(), (cfg.vocab_size, cfg.d_model), pd, fan_in=cfg.d_model)
 
-    def init_period():
-        return [_KIND_INIT[k](kg, cfg, rt) for k in plan.period_kinds]
+    def init_period(draw):
+        return [_KIND_INIT[k](draw, cfg, rt) for k in plan.period_kinds]
 
+    params["scan"] = []
     if plan.n_periods:
-        periods = [init_period() for _ in range(plan.n_periods)]
-        # list over period positions; each leaf stacked over n_periods
-        params["scan"] = [_stack([p[i] for p in periods])
-                         for i in range(len(plan.period_kinds))]
-    else:
-        params["scan"] = []
+        # each period replays the keys a layer-by-layer build draws from
+        # ``kg``, so a seed gives the same weights however they are built;
+        # list over period positions, each leaf stacked over n_periods
+        n_draws = _count_draws(init_period)
+        keys = jnp.stack([jnp.stack([kg() for _ in range(n_draws)])
+                          for _ in range(plan.n_periods)])
+        params["scan"] = jax.vmap(lambda ks: init_period(_Replay(ks)))(keys)
     params["tail"] = [_KIND_INIT[k](kg, cfg, rt) for k in plan.tail_kinds]
     return params
+
+
+class _Replay:
+    """A ``KeyGen`` stand-in that hands out the given keys in order."""
+
+    def __init__(self, keys: jax.Array):
+        self._keys, self._i = keys, 0
+
+    def __call__(self) -> jax.Array:
+        self._i += 1
+        return self._keys[self._i - 1]
+
+
+def _count_draws(init) -> int:
+    """How many keys ``init(draw)`` takes from its dispenser."""
+    n = 0
+
+    def draw():
+        nonlocal n
+        n += 1
+        return jax.random.PRNGKey(0)
+    jax.eval_shape(lambda: init(draw))
+    return n
 
 
 # ---------------------------------------------------------------------------

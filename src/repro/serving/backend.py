@@ -213,15 +213,21 @@ class _SlotCacheBackend(ExecutionBackend):
     paged caches.  Subclasses implement ``decode``."""
 
     def __init__(self, cfg: ModelConfig, params, rt: Runtime, *,
-                 mb_size: int, num_microbatches: int, pool: kvc.PoolConfig):
+                 mb_size: int, num_microbatches: int, pool: kvc.PoolConfig,
+                 place=None):
+        """``place`` (tree -> tree of shardings) puts the weights and
+        builds the caches where the compute plane keeps them; None leaves
+        the weights where they are and the caches on the default device."""
         self.cfg = cfg
-        self.params = params
+        self.params = params if place is None else \
+            jax.device_put(params, place(params))
         self.rt = rt
         self.mb_size = mb_size
         self.num_microbatches = num_microbatches
         self.batch = mb_size * num_microbatches
         self.pool = pool
-        self.caches = kvc.build_paged_caches(cfg, self.batch, pool, rt)
+        self.caches = kvc.build_paged_caches(cfg, self.batch, pool, rt,
+                                             place=place)
         self._prefill_jits: Dict[int, object] = {}
 
     # -- cache bookkeeping entry points ------------------------------------
@@ -420,35 +426,35 @@ class PipelinedBackend(_SlotCacheBackend):
                 f"continuous batching over a {n_stages}-stage pipe needs "
                 f"N_B >= N_S (got N_B={num_microbatches}); see §4.3 — a "
                 "microbatch must drain before its next injection")
+        if mesh is None:
+            mesh = PL.stage_mesh(n_stages)
+        self.mesh = mesh
+        # Each stage's weights and caches live on that stage's device from
+        # the start (PL.stage_shardings): the period stack is split over
+        # "pod", embeddings / final norm / tail are replicated.  A model
+        # too large for one chip is then served without ever being whole
+        # on one.
+        place = PL.stage_shardings(cfg, mesh, n_stages)
         super().__init__(cfg, params, rt, mb_size=mb_size,
-                         num_microbatches=num_microbatches, pool=pool)
+                         num_microbatches=num_microbatches, pool=pool,
+                         place=place)
         self.n_stages = n_stages
         self.pps, self.leftover = PL.split_layers(cfg, n_stages)
-        if mesh is None:
-            devs = jax.devices()
-            if len(devs) < n_stages:
-                raise RuntimeError(
-                    f"pipelined backend needs >= {n_stages} devices for the "
-                    f"pod axis, have {len(devs)} — set XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={n_stages} "
-                    "before initialising jax, or reduce --stages")
-            mesh = jax.sharding.Mesh(np.array(devs[:n_stages]), ("pod",))
-        self.mesh = mesh
         # Sharding discipline for the persistent tick jits: every array
         # input must carry ONE stable sharding per serve run, or the jit
         # cache key flips and the tick silently recompiles (caught by the
-        # strict-mode jit probes).  Fresh jnp.zeros and host-side table
-        # writes are SingleDeviceSharding-uncommitted while tick outputs
-        # come back NamedSharding-committed (stage-stacked leaves
-        # P("pod")), so: (1) state starts replicated-committed; (2) just
-        # before the first tick of each plane, _probe_layout AOT-compiles
-        # the tick and commits the state to the compiled OUTPUT shardings
-        # — the layout every later tick hands back — so the counted call
-        # cache only ever sees the steady layout; (3) every later
-        # host-side write re-commits through _commit() to it.
+        # strict-mode jit probes).  Host-side table writes are
+        # SingleDeviceSharding-uncommitted while tick outputs come back
+        # NamedSharding-committed, so: (1) the caches start committed to
+        # the stage placement; (2) just before the first tick of each
+        # plane, _probe_layout AOT-compiles the tick and commits the state
+        # to the compiled OUTPUT shardings — the layout every later tick
+        # hands back — so the counted call cache only ever sees the
+        # steady layout; (3) every later host-side write re-commits
+        # through _commit() to it.
         self._replicated = jax.sharding.NamedSharding(
             mesh, jax.sharding.PartitionSpec())
-        self._cache_shardings = None    # learned on the first tick
+        self._cache_shardings = place(self.caches)   # until the probe
         self._act_sharding = None
         self._pf_act_sharding = None
         self._layout_learned = {"decode": False, "prefill": False}
@@ -611,13 +617,11 @@ class PipelinedBackend(_SlotCacheBackend):
 
     def _commit(self, tree):
         """Pin every cache leaf to the steady tick-jit layout (or the
-        replicated bootstrap before the first tick learned it).
-        Host-side writes (page-table publish, slot reset, offload splice,
-        exact prefill) otherwise hand the next tick arrays whose sharding
+        stage placement before the first tick learned it).  Host-side
+        writes (page-table publish, slot reset, offload splice, exact
+        prefill) otherwise hand the next tick arrays whose sharding
         differs from the previous tick's outputs — a silent jit cache-key
         flip and recompile.  A no-op for already-committed leaves."""
-        if self._cache_shardings is None:
-            return jax.device_put(tree, self._replicated)
         return jax.tree.map(jax.device_put, tree, self._cache_shardings)
 
     def _probe_layout(self, plane: str, args: tuple) -> None:
@@ -629,7 +633,7 @@ class PipelinedBackend(_SlotCacheBackend):
         only way to keep steady state at exactly one compile per (shape,
         wire_dtype) config is to never let the bootstrap layout reach a
         counted call.  ``.lower().compile()`` does not populate
-        ``_cache_size`` (verified on jax 0.4.37), so the probe itself is
+        ``_cache_size`` (verified on jax 0.9.0), so the probe itself is
         invisible to the strict-mode jit probes."""
         self._layout_learned[plane] = True
         if plane == "decode":

@@ -21,6 +21,7 @@ page lists) is host-side Python — identical to vLLM's split of concerns.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -333,9 +334,18 @@ def _paged_kind_cache(cfg: ModelConfig, batch: int, pool: PoolConfig,
 
 
 def build_paged_caches(cfg: ModelConfig, batch: int, pool: PoolConfig,
-                       rt: Runtime) -> dict:
+                       rt: Runtime, place=None) -> dict:
     """Engine cache pytree: paged pools for full-attention kinds, dense rings
-    for sliding-window kinds, O(1) states for recurrent kinds."""
+    for sliding-window kinds, O(1) states for recurrent kinds.  ``place``
+    (shape tree -> sharding tree) builds each leaf where it will live."""
+    build = functools.partial(_build_paged_caches, cfg, batch, pool, rt)
+    if place is None:
+        return build()
+    return jax.jit(build, out_shardings=place(jax.eval_shape(build)))()
+
+
+def _build_paged_caches(cfg: ModelConfig, batch: int, pool: PoolConfig,
+                        rt: Runtime) -> dict:
     plan = make_layer_plan(cfg.num_layers, cfg.block_pattern)
 
     def kind_cache(kind: str, lead: tuple):

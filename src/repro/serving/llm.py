@@ -342,30 +342,43 @@ class LLM:
     By default the registered arch is shrunk with ``reduced_config`` (CPU
     scale) and parameters are randomly initialised from ``config.seed``;
     pass ``reduced=False`` and/or ``params=`` for real deployments.
+    Without ``rt`` the dtype follows the configuration: bf16 at published
+    widths, fp32 for reduced ones (``models.common.serving_runtime``).
+    On the pipelined backend the random parameters are built where each
+    stage keeps them, never whole on one device.
     """
 
     def __init__(self, model: Union[str, ModelConfig], *,
                  config: Optional[EngineConfig] = None, params=None,
                  rt=None, reduced: bool = True):
         import jax
-        import jax.numpy as jnp
 
         from repro.models import model as model_lib
-        from repro.models.common import Runtime
+        from repro.models.common import serving_runtime
 
         cfg = get_arch(model) if isinstance(model, str) else model
         if reduced and isinstance(model, str):
             cfg = reduced_config(cfg)
         self.config = config or EngineConfig()
         self.cfg = cfg
-        self.rt = rt or Runtime(param_dtype=jnp.float32,
-                                compute_dtype=jnp.float32)
+        self.rt = rt or serving_runtime(cfg)
         if params is None:
             params = model_lib.init_params(
-                cfg, jax.random.PRNGKey(self.config.seed), self.rt)
+                cfg, jax.random.PRNGKey(self.config.seed), self.rt,
+                shardings=self._stage_placement())
         self.params = params
         self.engine = self.config.build(cfg, params, self.rt)
         self._next_id = 0
+
+    def _stage_placement(self):
+        """The pipelined backend's per-stage placement, or None for the
+        local backend."""
+        from repro.core import pipeline as PL
+        if self.config.backend != "pipelined":
+            return None
+        n = self.config.n_stages
+        return PL.stage_shardings(self.cfg,
+                                  self.config.mesh or PL.stage_mesh(n), n)
 
     # ------------------------------------------------------------------
 

@@ -191,3 +191,48 @@ def test_local_pipelined_greedy_equivalence(arch):
                        capture_output=True, text=True, timeout=560)
     assert r.returncode == 0, f"{r.stdout}\n{r.stderr[-2000:]}"
     assert "OK" in r.stdout
+
+
+PLACEMENT_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax, numpy as np
+from repro.config import get_arch, reduced_config
+from repro.models import model as M
+from repro.serving.kv_cache import PoolConfig
+from repro.serving.llm import LLM, EngineConfig
+
+cfg = reduced_config(get_arch("yi-9b"), num_layers=4)
+pool = PoolConfig(page_size=4, n_local_pages=8, n_global_pages=0,
+                  max_pages_per_seq=4)
+llm = LLM(cfg, config=EngineConfig(backend="pipelined", n_stages=2,
+                                   mb_size=1, num_microbatches=2, pool=pool,
+                                   offload=False))
+backend = llm.engine.backend
+devs = list(backend.mesh.devices.flat)
+for tree in (llm.params, backend.params, backend.caches):
+    for leaf in jax.tree.leaves(tree["scan"]):
+        # stage s holds periods [2s, 2s+2) of the 4-period stack, only them
+        shards = sorted(leaf.addressable_shards, key=lambda s: s.device.id)
+        assert [s.device for s in shards] == devs
+        assert [s.index[0] for s in shards] == [slice(0, 2), slice(2, 4)]
+for leaf in jax.tree.leaves(llm.params["embed"]):
+    assert leaf.sharding.is_fully_replicated
+# placed as it is built: the same values as a default-device build
+ref = M.init_params(cfg, jax.random.PRNGKey(llm.config.seed), llm.rt)
+for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(llm.params)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("OK")
+"""
+
+
+def test_pipelined_weights_and_caches_live_on_their_stage():
+    """The pipelined backend never holds the model whole on one device:
+    each stage's periods of the weights and caches are built on that
+    stage's device, the embedding is replicated."""
+    from equivalence import subprocess_env
+    r = subprocess.run([sys.executable, "-c", PLACEMENT_SCRIPT],
+                       env=subprocess_env({}), capture_output=True,
+                       text=True, timeout=300)
+    assert r.returncode == 0, f"{r.stdout}\n{r.stderr[-2000:]}"
+    assert "OK" in r.stdout

@@ -105,6 +105,27 @@ def test_reduced_config_keeps_family(name):
     assert red.param_count() < 30e6
 
 
+@pytest.mark.parametrize("name", ASSIGNED_ARCHS)
+def test_served_dtype_follows_the_widths(name):
+    """bf16 at published widths (depth may be cut), fp32 for the reduced
+    configs the CPU tests compare bitwise."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from repro.config import at_published_widths
+    from repro.models.common import serving_runtime
+    cfg = get_arch(name)
+    cut = dataclasses.replace(cfg, num_layers=len(cfg.block_pattern))
+    for c in (cfg, cut):
+        assert at_published_widths(c)
+        assert serving_runtime(c).param_dtype == jnp.bfloat16
+    red = tiny(name)
+    assert not at_published_widths(red)
+    assert serving_runtime(red).param_dtype == jnp.float32
+    assert not at_published_widths(dataclasses.replace(cfg, d_model=64))
+
+
 def test_paper_kv_cache_size_claim():
     """Paper §2.1: 'in the Llama 3 70B model, the KV cache for a sequence of
     length 4096 can occupy 1.25 GB' — our config computes 1.34 GB at bf16

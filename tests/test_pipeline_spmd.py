@@ -21,7 +21,8 @@ from repro.models.common import Runtime
 from repro.core import pipeline as PL
 
 rt = Runtime(param_dtype=jnp.float32, compute_dtype=jnp.float32)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 arch = os.environ["PIPE_ARCH"]
 cfg0 = get_arch(arch)
 period = len(cfg0.block_pattern)
@@ -89,7 +90,8 @@ from repro.models.common import Runtime
 from repro.core import pipeline as PL
 
 rt = Runtime(param_dtype=jnp.float32, compute_dtype=jnp.float32)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 cfg0 = get_arch(os.environ["PIPE_ARCH"])
 period = len(cfg0.block_pattern)
 cfg = reduced_config(cfg0, num_layers=2 * period + (2 if period > 1 else 1))

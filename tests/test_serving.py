@@ -263,11 +263,9 @@ def test_offload_backend_gating(rt):
     store; the schedule/bookkeeping is identical either way (DESIGN §3)."""
     from repro.core import offload as OF
     assert not OF.host_memory_available()        # CPU container
-    mesh = jax.make_mesh((1,), ("data",))
-    sh = OF.pool_shardings(mesh, jax.sharding.PartitionSpec(), host=True)
-    # degrades to the backend's default memory kind, not pinned_host
-    assert sh.memory_kind in (None, jax.devices()[0].default_memory().kind)
-    off = DoubleBufferOffloader(
-        PoolConfig(page_size=4, n_local_pages=4, n_global_pages=2,
-                   max_pages_per_seq=4), 2)
-    assert OF.place_host_store(off, mesh, jax.sharding.PartitionSpec()) is off
+    x = jnp.arange(8.0)
+    # no host memory space: the swap-out keeps the snapshot where it is
+    assert OF._pinned_host(x) is None
+    # a host-store entry in default memory splices back unchanged
+    assert OF._on_device(x) is x
+    np.testing.assert_array_equal(OF._on_device(np.ones(3)), np.ones(3))
