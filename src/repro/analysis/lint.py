@@ -23,7 +23,7 @@ offloader exists to hide.  The deliberate sync fallback
 **obs-hot-path** — flight-recorder discipline for ``repro.obs``: a
 recording call (any dotted call routed through a recorder name —
 ``self.recorder.span(...)``, ``rec.link_send(...)``; ``obs_roots`` in
-config) is flagged inside a tick-jit body (the recorder is host-side
+config — or a program span, ``obs.span(...)``) is flagged inside a tick-jit body (the recorder is host-side
 only — a call under tracing either fails or bakes one trace's stamps
 into the compiled graph), and, anywhere in the reachable hot set, when
 an argument references a *device-tracked* value — recording must read
@@ -111,6 +111,9 @@ DEFAULT_OFFLOAD_WINDOWS = [
 # routes through one of these (``self.recorder.span``, ``rec.fault``) is
 # an obs recording call for the obs-hot-path pass
 DEFAULT_OBS_ROOTS = ["recorder", "rec"]
+# the program-span helper: a call by one of these dotted names is a
+# recording call too
+OBS_CALLS = {"obs.span", "repro.obs.span"}
 
 RULES = ("host-sync", "offload-sync", "obs-hot-path", "retrace-jit",
          "retrace-branch", "retrace-nonhashable", "prng-reuse",
@@ -581,7 +584,8 @@ def _obs_pass(files: Sequence[FileIndex], cfg: AuditConfig,
               reachable: Set[str]) -> List[Violation]:
     """Flight-recorder discipline (``repro.obs``).  A recording call is
     any dotted call whose path routes through an ``obs_roots`` name
-    (``self.recorder.span``, ``rec.link_send``).  Two failure modes:
+    (``self.recorder.span``, ``rec.link_send``), or a program span
+    (``obs.span``).  Two failure modes:
 
     * inside a tick-jit body (``traced_fns``) *every* recording call is
       flagged — the recorder is host-side; under tracing the call either
@@ -595,6 +599,8 @@ def _obs_pass(files: Sequence[FileIndex], cfg: AuditConfig,
     roots = set(cfg.obs_roots)
 
     def _is_obs(name: str) -> bool:
+        if name in OBS_CALLS:
+            return True
         parts = name.split(".")
         # the final component is the method; any earlier component being
         # a recorder name makes this a recording call

@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Union
@@ -36,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.serving.kv_cache import PoolConfig, global_slice
 
 # default bandwidth constants (bytes/s)
@@ -179,19 +179,16 @@ class DoubleBufferOffloader:
         layers = list(self._paged_layers(caches))
         rec = self.recorder
         if out_mb is not None:
-            self._host[out_mb] = self._dispatch_stage_out(layers, sl)
-            if rec is not None:
-                rec.offload_swap_out(out_mb, time.perf_counter(),
-                                     self.async_swap)
+            with obs.span("kv.swap_out", rec,
+                          (("mb", out_mb), ("async", self.async_swap))):
+                self._host[out_mb] = self._dispatch_stage_out(layers, sl)
 
         incoming = self._host.pop(mb, None)
         if isinstance(incoming, Future):
             # the wait window: the part of the staged copy the
             # double-buffer failed to hide under the previous tick
-            t0 = time.perf_counter()
-            incoming = incoming.result()
-            if rec is not None:
-                rec.offload_swap_in(mb, t0, time.perf_counter())
+            with obs.span("kv.swap_wait", rec, (("mb", mb),)):
+                incoming = incoming.result()
         if incoming is None and out_mb is not None:
             # first touch for this microbatch while the pool holds another
             # one's content: zero-fill (hygiene — stale KV is masked by

@@ -54,9 +54,10 @@ bit-identical to the offline path in all of these modes.
 
 Observability: ``--trace out.json`` turns on the flight recorder
 (``EngineConfig(trace=True)``) and writes a Perfetto-loadable
-Chrome-trace timeline at exit — engine step phases, per-microbatch stage
-occupancy, per-link transfers on the virtual clock, offload swaps,
-prefix-cache and SLO events; ``--metrics`` keeps a
+Chrome-trace timeline at exit — the program's spans (``sched.*``,
+``kv.*``, ``step.*``, ``front.*``; the same spans a profiler trace
+shows), per-microbatch stage occupancy, per-link transfers on the
+virtual clock, prefix-cache and SLO events; ``--metrics`` keeps a
 counter/gauge/histogram registry over the run, printing a one-line stats
 banner every ``--metrics-every`` engine steps and the full Prometheus
 exposition text at exit.

@@ -227,6 +227,8 @@ def update_from_engine(metrics: Metrics, engine) -> Dict[str, float]:
                       ("repro_decode_tokens_total", "decode_tokens"),
                       ("repro_prefill_tokens_total", "prefill_tokens"),
                       ("repro_requests_finished_total", "finished"),
+                      ("repro_admitted_total", "admitted"),
+                      ("repro_queue_wait_seconds_total", "queue_wait_s"),
                       ("repro_engine_steps_total", "steps"),
                       ("repro_offload_swaps_total", "swaps"),
                       ("repro_prefix_hits_total", "prefix_hits"),

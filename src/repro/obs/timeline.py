@@ -6,12 +6,12 @@ directly: open https://ui.perfetto.dev and drop the file in.
 
 Track layout — the picture the §4.3 schedule comparison needs:
 
-* **pid 1 "wall clock"** — the engine's real time: one ``engine`` track
-  of step phases (reap / prefill / decode), one ``pipe/<plane>`` track
-  per pipe plane (each tick a slice whose args carry the stage
-  occupancy), the ``offload`` swap windows, and instant markers for
-  prefix-cache hits/evictions, SLO budget decisions, faults, reshard
-  drain/rebuild.
+* **pid 1 "wall clock"** — the engine's real time: one track per layer
+  of program spans (``sched``, ``kv``, ``step``, ``front``, ``host``;
+  nested spans of a layer nest on its track), one ``pipe/<plane>``
+  track per pipe plane (each tick a slice whose args carry the stage
+  occupancy), and instant markers for prefix-cache hits/evictions, SLO
+  budget decisions, faults, reshard drain/rebuild.
 * **pid 2 "virtual clock"** — the transport's simulated time: one
   ``stage<s>`` track per pipeline stage (busy windows — the circular
   schedule shows as a dense brick wall, round-flush as bubbles), and
@@ -67,8 +67,17 @@ def chrome_trace_events(rec: TraceRecorder) -> Dict:
     # has an arbitrary epoch); the virtual clock already starts at 0
     wall0 = min((e.t0 for e in rec.events if e.clock == WALL),
                 default=0.0)
+    # a span reaches the ring when it closes, so an enclosing span lands
+    # after the spans it encloses: spans go out in order of their start
+    # (stable), into the places spans held, and every other event keeps
+    # its place and order
+    ordered = list(rec.events)
+    at = [i for i, e in enumerate(ordered) if e.kind == SPAN]
+    for i, e in zip(at, sorted((ordered[i] for i in at),
+                               key=lambda e: e.t0)):
+        ordered[i] = e
     async_id = 0
-    for e in rec.events:
+    for e in ordered:
         pid = _PIDS[e.clock]
         tid = tid_of(pid, e.track)
         t0 = e.t0 - wall0 if e.clock == WALL else e.t0

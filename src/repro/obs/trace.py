@@ -1,4 +1,19 @@
-"""Ring-buffer span/event recorder with a dual timeline.
+"""Program spans on the profiler clock, and the ring-buffer recorder
+behind them.
+
+:class:`span` is the one way the serving core marks where the host is:
+``with span("sched.reap", recorder):`` always opens a
+``jax.profiler.TraceAnnotation`` of that name, which lands on the
+profiler's host plane on the same clock as the device's ``XLA Ops``, so
+every gap in the device's work can be laid against the layer the host
+was in.  With a recorder attached the same span is also appended to its
+ring (``perf_counter`` stamps, on a track named for the span's layer
+prefix: ``sched``, ``kv``, ``step``, ``front``, ``host``), which is what
+``write_chrome_trace`` exports.  Rules for call sites: no keyword
+arguments to the annotation, no span inside a per-row or per-stream
+loop, and none inside a jitted body (the ``obs-hot-path`` lint rule
+flags the last, and a span fed a device value).  With the profiler off a
+span costs under a microsecond.
 
 One :class:`TraceRecorder` serves one engine.  Every recording helper is
 a plain host-side append of already-materialised values — a perf_counter
@@ -11,8 +26,8 @@ zero when tracing is off.
 
 Two clocks, tagged per event:
 
-* ``"wall"`` — ``time.perf_counter()`` seconds.  Engine step phases,
-  pipe ticks, offload windows, per-request latency stamps.
+* ``"wall"`` — ``time.perf_counter()`` seconds.  Program spans, pipe
+  ticks, per-request latency stamps.
 * ``"virtual"`` — the transport layer's :class:`~repro.distributed
   .transport.VirtualClock` seconds.  Per-stage busy windows, per-link
   transfers, stall ledger entries.  A 64 ms WAN run records a 64 ms
@@ -35,11 +50,15 @@ on ``RequestOutput.trace``.
 
 from __future__ import annotations
 
+import gc
 import time
 from collections import deque
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
-__all__ = ["Event", "TraceRecorder", "WALL", "VIRTUAL"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Event", "TraceRecorder", "WALL", "VIRTUAL", "span",
+           "install_gc_spans"]
 
 WALL = "wall"
 VIRTUAL = "virtual"
@@ -99,15 +118,7 @@ class TraceRecorder:
                 clock: str = WALL, data: Tuple = ()) -> None:
         self._append(INSTANT, name, clock, track, t, 0.0, data)
 
-    # -- engine step phases (wall clock) ----------------------------------
-
-    def step_phase(self, name: str, t0: float, t1: float,
-                   step: int) -> None:
-        """One phase of one engine step: "reap" / "prefill" / "decode" /
-        "admit" — recorded from the stamps ``OfflineEngine.step`` takes
-        for ``EngineStats`` anyway."""
-        self._append(SPAN, name, WALL, "engine", t0, t1 - t0,
-                     (("step", step),))
+    # -- pipe ticks (wall clock) ------------------------------------------
 
     def pipe_tick(self, plane: str, t0: float, t1: float,
                   occupancy: Tuple) -> None:
@@ -157,20 +168,6 @@ class TraceRecorder:
             elif e.kind == COUNTER and e.name == "stall":
                 stall += e.data[0][1]
         return {"wire_bytes": wire, "sends": sends, "stall_s": stall}
-
-    # -- offload windows (wall clock) -------------------------------------
-
-    def offload_swap_out(self, mb: int, t: float, asynchronous: bool
-                         ) -> None:
-        self._append(INSTANT, "swap_out", WALL, "offload", t, 0.0,
-                     (("mb", mb), ("async", asynchronous)))
-
-    def offload_swap_in(self, mb: int, t0: float, t1: float) -> None:
-        """The swap-in wait window: how long ``ensure_resident`` blocked
-        on the staged copy (t1 - t0 is the part the double-buffer failed
-        to hide under compute)."""
-        self._append(SPAN, "swap_in", WALL, "offload", t0, t1 - t0,
-                     (("mb", mb),))
 
     # -- scheduler decisions (wall clock) ---------------------------------
 
@@ -311,3 +308,69 @@ class TraceRecorder:
         return {"events": len(self.events), "dropped": self.dropped,
                 "requests": len(self.requests),
                 **self.link_ledger()}
+
+
+# ---------------------------------------------------------------------------
+# Program spans
+# ---------------------------------------------------------------------------
+
+
+def span(name: str, recorder: Optional[TraceRecorder] = None,
+         data: Tuple = ()):
+    """``with span(name, recorder):`` marks a stretch of host work.
+
+    Always a ``jax.profiler.TraceAnnotation(name)`` (a no-op unless the
+    profiler is tracing); with a ``recorder`` the span also goes to its
+    ring on the track named by ``name``'s prefix before the first dot,
+    carrying ``data`` (ring only).  Names are ``<layer>.<what>``."""
+    if recorder is None:
+        return TraceAnnotation(name)
+    return _RecordedSpan(name, recorder, data)
+
+
+class _RecordedSpan:
+    """A profiler annotation that is also appended to a recorder's ring
+    when it closes."""
+
+    __slots__ = ("name", "recorder", "data", "_ann", "_t0")
+
+    def __init__(self, name: str, recorder: TraceRecorder, data: Tuple):
+        self.name = name
+        self.recorder = recorder
+        self.data = data
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.recorder.span(self.name, self.name.split(".", 1)[0], self._t0,
+                           time.perf_counter(), data=self.data)
+        self._ann.__exit__(*exc)
+
+
+def install_gc_spans(recorder: Optional[TraceRecorder] = None
+                     ) -> Callable[[], None]:
+    """Open a ``host.gc`` span around every collection of Python's
+    garbage collector (a ``gc.callbacks`` hook; the ring's copy carries
+    the generation collected).  Opt in: a collection then costs two
+    spans.  Returns the function that removes the hook."""
+    open_spans: list = []
+
+    def hook(phase: str, info: dict) -> None:
+        if phase == "start":
+            s = span("host.gc", recorder,
+                     (("generation", info["generation"]),))
+            s.__enter__()
+            open_spans.append(s)
+        elif open_spans:
+            open_spans.pop().__exit__(None, None, None)
+
+    gc.callbacks.append(hook)
+
+    def remove() -> None:
+        if hook in gc.callbacks:
+            gc.callbacks.remove(hook)
+    return remove

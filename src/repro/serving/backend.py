@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.models import model as model_lib
 from repro.models.common import Runtime
@@ -119,6 +120,16 @@ class PrefillResult:
 # backends are their main consumer
 slot_view = kvc.slot_view
 slot_merge = kvc.slot_merge
+
+
+def named_partial(fn, **static):
+    """``functools.partial(fn, **static)`` under ``fn``'s own name, so
+    ``jax.jit`` of it lowers as ``jit_<name>`` (``jit__decode_fn``) and
+    a profiler trace names the program, where a bare partial lowers as
+    ``jit__unknown``."""
+    p = functools.partial(fn, **static)
+    p.__name__ = fn.__name__
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -212,6 +223,8 @@ class _SlotCacheBackend(ExecutionBackend):
     """Shared prefill / page-table / reset plumbing over engine-format
     paged caches.  Subclasses implement ``decode``."""
 
+    recorder = None             # the engine's TraceRecorder, or None
+
     def __init__(self, cfg: ModelConfig, params, rt: Runtime, *,
                  mb_size: int, num_microbatches: int, pool: kvc.PoolConfig,
                  place=None):
@@ -233,10 +246,19 @@ class _SlotCacheBackend(ExecutionBackend):
     # -- cache bookkeeping entry points ------------------------------------
 
     def set_page_table(self, table: np.ndarray) -> None:
-        self.caches = kvc.set_page_table(self.caches, table)
+        with obs.span("kv.publish", self.recorder):
+            self.caches = self._commit(
+                kvc.set_page_table(self.caches, table))
 
     def reset_slot(self, slot: int) -> None:
-        self.caches = kvc.reset_slot(self.caches, self.cfg, slot, self.rt)
+        with obs.span("kv.publish", self.recorder):
+            self.caches = self._commit(
+                kvc.reset_slot(self.caches, self.cfg, slot, self.rt))
+
+    def _commit(self, tree):
+        """Place caches a host-side write produced where the tick jits
+        expect them (here: where they are)."""
+        return tree
 
     def jit_entries(self) -> Dict[str, object]:
         return {f"_prefill_jits[{lp}]": fn
@@ -260,11 +282,12 @@ class _SlotCacheBackend(ExecutionBackend):
             # dict holds O(log max_len) wrappers, each built once and
             # reused — not a per-call jit
             # repro-audit: allow(retrace-jit) — bounded per-length cache, one wrapper per bucketed length
-            self._prefill_jits[lp] = jax.jit(functools.partial(
+            self._prefill_jits[lp] = jax.jit(named_partial(
                 self._prefill_fn, cfg=self.cfg, rt=self.rt))
         fn = self._prefill_jits[lp]
-        logits, self.caches = fn(self.params, jnp.asarray(tokens)[None],
-                                 self.caches, slot, last_index)
+        logits, caches = fn(self.params, jnp.asarray(tokens)[None],
+                            self.caches, slot, last_index)
+        self.caches = self._commit(caches)
         return logits
 
     # -- chunked prefill ---------------------------------------------------
@@ -332,10 +355,10 @@ class LocalBackend(_SlotCacheBackend):
         self.recorder = recorder
         if offloader is not None:
             offloader.recorder = recorder
-        self._decode_jit = jax.jit(functools.partial(
+        self._decode_jit = jax.jit(named_partial(
             self._decode_fn, cfg=cfg, rt=rt, mb_size=mb_size,
             sample_fast=sample_fast_path))
-        self._chunk_jit = jax.jit(functools.partial(
+        self._chunk_jit = jax.jit(named_partial(
             self._chunk_fn, cfg=cfg, rt=rt))
 
     def _prefill_residency(self, mb: int) -> None:
@@ -353,12 +376,15 @@ class LocalBackend(_SlotCacheBackend):
             return []
         for mb in chunk.residency_mbs:
             self._prefill_residency(mb)
-        logits, self.caches = self._chunk_jit(
-            self.params, self.caches, jnp.asarray(chunk.tokens),
-            jnp.asarray(chunk.offsets), jnp.asarray(chunk.n_valid),
-            jnp.asarray(chunk.lasts), jnp.asarray(chunk.tables))
-        # repro-audit: allow(host-sync) — prefill drain: the engine samples the first token from these logits on host, once per chunk
-        return [PrefillResult(chunk=chunk, logits=np.asarray(logits))]
+        with obs.span("step.chunk_dispatch", self.recorder):
+            logits, self.caches = self._chunk_jit(
+                self.params, self.caches, jnp.asarray(chunk.tokens),
+                jnp.asarray(chunk.offsets), jnp.asarray(chunk.n_valid),
+                jnp.asarray(chunk.lasts), jnp.asarray(chunk.tables))
+        with obs.span("step.chunk_readback", self.recorder):
+            # repro-audit: allow(host-sync) — prefill drain: the engine samples the first token from these logits on host, once per chunk
+            logits = np.asarray(logits)
+        return [PrefillResult(chunk=chunk, logits=logits)]
 
     def decode(self, mb: int, tokens: np.ndarray, cur_pos: np.ndarray,
                samp: RowSampling, active: bool = True) -> List[DecodeResult]:
@@ -366,18 +392,20 @@ class LocalBackend(_SlotCacheBackend):
             return []
         if self.offloader is not None:
             self.caches = self.offloader.ensure_resident(self.caches, mb)
-        toks, lps, self.caches = self._decode_jit(
-            self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(cur_pos), jnp.int32(mb * self.mb_size),
-            jnp.asarray(samp.keys), jnp.asarray(samp.steps),
-            jnp.asarray(samp.temp), jnp.asarray(samp.top_k),
-            jnp.asarray(samp.top_p))
+        with obs.span("step.decode_dispatch", self.recorder):
+            toks, lps, self.caches = self._decode_jit(
+                self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(cur_pos), jnp.int32(mb * self.mb_size),
+                jnp.asarray(samp.keys), jnp.asarray(samp.steps),
+                jnp.asarray(samp.temp), jnp.asarray(samp.top_k),
+                jnp.asarray(samp.top_p))
         # §4.3 return link: the host-driven engine books the drained
         # microbatch's token ids, so one transfer per decode call is the
         # loop's single intended sync point — batched (tokens, logprobs)
         # in one device_get rather than two separate np.asarray syncs
-        # repro-audit: allow(host-sync) — intended §4.3 return-link sync, one batched transfer per drain
-        toks, lps = jax.device_get((toks, lps))
+        with obs.span("step.decode_readback", self.recorder):
+            # repro-audit: allow(host-sync) — intended §4.3 return-link sync, one batched transfer per drain
+            toks, lps = jax.device_get((toks, lps))
         return [DecodeResult(mb=mb, tokens=toks, logprobs=lps)]
 
     @staticmethod
@@ -467,7 +495,7 @@ class PipelinedBackend(_SlotCacheBackend):
         # (mb, positions-at-injection, RowSampling-at-injection) whose
         # activation sits in act[s]
         self._entries: List[Optional[tuple]] = [None] * n_stages
-        self._tick_jit = jax.jit(functools.partial(
+        self._tick_jit = jax.jit(named_partial(
             PL.pipeline_decode_tick, cfg=cfg, rt=rt,
             n_stages=n_stages, mb_size=mb_size, mesh=mesh,
             wire_dtype=wire_dtype, sample_fast_path=sample_fast_path))
@@ -478,7 +506,7 @@ class PipelinedBackend(_SlotCacheBackend):
         # activation buffer and jit are built lazily on the first chunk.
         self._pf_entries: List[Optional[PrefillChunk]] = [None] * n_stages
         self._pf_act = None
-        self._pf_tick_jit = jax.jit(functools.partial(
+        self._pf_tick_jit = jax.jit(named_partial(
             PL.pipeline_prefill_chunk_tick, cfg=cfg, rt=rt,
             n_stages=n_stages, mesh=mesh, wire_dtype=wire_dtype))
         # Probe the decode plane NOW (arg shapes are fixed by n_stages and
@@ -647,20 +675,6 @@ class PipelinedBackend(_SlotCacheBackend):
             self._pf_act = jax.device_put(self._pf_act,
                                           self._pf_act_sharding)
         self.caches = self._commit(self.caches)
-
-    def set_page_table(self, table: np.ndarray) -> None:
-        super().set_page_table(table)
-        self.caches = self._commit(self.caches)
-
-    def reset_slot(self, slot: int) -> None:
-        super().reset_slot(slot)
-        self.caches = self._commit(self.caches)
-
-    def prefill(self, tokens: np.ndarray, slot: int, last_index: int,
-                has_global_pages: bool = True) -> jax.Array:
-        logits = super().prefill(tokens, slot, last_index, has_global_pages)
-        self.caches = self._commit(self.caches)
-        return logits
 
     def _ensure_stage_resident(self, s: int, mb: int) -> None:
         if not self._stage_off:
@@ -905,24 +919,26 @@ class PipelinedBackend(_SlotCacheBackend):
         lasts = drained.lasts if drained is not None \
             else np.zeros((rows,), np.int32)
 
-        tick_args = (jnp.asarray(tokens, jnp.int32), jnp.asarray(offs),
-                     jnp.asarray(nval), jnp.asarray(tabs),
-                     jnp.asarray(lasts, jnp.int32), jnp.int32(drop_stage))
-        if not self._layout_learned["prefill"]:
-            self._probe_layout(
-                "prefill",
-                (self.params, self.caches, self._pf_act) + tick_args)
-        t0 = time.perf_counter()
-        logits, self.caches, self._pf_act = self._pf_tick_jit(
-            self.params, self.caches, self._pf_act, *tick_args)
-        t1 = time.perf_counter()
+        with obs.span("step.chunk_dispatch", self.recorder):
+            tick_args = (jnp.asarray(tokens, jnp.int32), jnp.asarray(offs),
+                         jnp.asarray(nval), jnp.asarray(tabs),
+                         jnp.asarray(lasts, jnp.int32),
+                         jnp.int32(drop_stage))
+            if not self._layout_learned["prefill"]:
+                self._probe_layout(
+                    "prefill",
+                    (self.params, self.caches, self._pf_act) + tick_args)
+            t0 = time.perf_counter()
+            logits, self.caches, self._pf_act = self._pf_tick_jit(
+                self.params, self.caches, self._pf_act, *tick_args)
+            t1 = time.perf_counter()
         dt = t1 - t0
         # the chunk activation (R, C, D) crosses each occupied boundary
-        obs = self.transport.tick(
+        link = self.transport.tick(
             [e is not None for e in entries],
             rows * clen * self.cfg.d_model * self._dtype_bytes,
             [dt / self.n_stages] * self.n_stages, plane="prefill")
-        self._observe_stages(dt, delays, obs.stalls)
+        self._observe_stages(dt, delays, link.stalls)
         if self.recorder is not None:
             # per-stage occupancy: prompt rows in flight at each stage
             # (host ints the stepper already holds)
@@ -933,8 +949,9 @@ class PipelinedBackend(_SlotCacheBackend):
         self._pf_entries = [None] + entries[:-1]
         if drained is None:
             return results
-        # repro-audit: allow(host-sync) — prefill drain: first-token logits leave the pipe for host-side sampling, once per chunk
-        logits = np.asarray(logits)
+        with obs.span("step.chunk_readback", self.recorder):
+            # repro-audit: allow(host-sync) — prefill drain: first-token logits leave the pipe for host-side sampling, once per chunk
+            logits = np.asarray(logits)
         return results + [PrefillResult(chunk=drained, logits=logits)]
 
     # -- the stepper --------------------------------------------------------
@@ -999,29 +1016,31 @@ class PipelinedBackend(_SlotCacheBackend):
         dsamp = drained[2] if drained is not None \
             else RowSampling.zeros(self.mb_size)
 
-        tick_args = (jnp.asarray(tokens, jnp.int32), jnp.asarray(mb_assign),
-                     jnp.asarray(pos_stage), jnp.asarray(dsamp.keys),
-                     jnp.asarray(dsamp.steps), jnp.asarray(dsamp.temp),
-                     jnp.asarray(dsamp.top_k), jnp.asarray(dsamp.top_p),
-                     jnp.int32(drop_stage))
-        if not self._layout_learned["decode"]:
-            self._probe_layout(
-                "decode", (self.params, self.caches, self.act) + tick_args)
-        t0 = time.perf_counter()
-        toks, lps, self.caches, self.act = self._tick_jit(
-            self.params, self.caches, self.act, *tick_args)
-        t1 = time.perf_counter()
+        with obs.span("step.decode_dispatch", self.recorder):
+            tick_args = (jnp.asarray(tokens, jnp.int32),
+                         jnp.asarray(mb_assign), jnp.asarray(pos_stage),
+                         jnp.asarray(dsamp.keys), jnp.asarray(dsamp.steps),
+                         jnp.asarray(dsamp.temp), jnp.asarray(dsamp.top_k),
+                         jnp.asarray(dsamp.top_p), jnp.int32(drop_stage))
+            if not self._layout_learned["decode"]:
+                self._probe_layout(
+                    "decode",
+                    (self.params, self.caches, self.act) + tick_args)
+            t0 = time.perf_counter()
+            toks, lps, self.caches, self.act = self._tick_jit(
+                self.params, self.caches, self.act, *tick_args)
+            t1 = time.perf_counter()
         dt = t1 - t0
         # the (mb_size, 1, D) activation crosses each occupied boundary;
         # an injection may not start before its microbatch's previous
         # drain returned over the last link (the §4.3 dependency)
-        obs = self.transport.tick(
+        link = self.transport.tick(
             [e is not None for e in entries],
             self.mb_size * self.cfg.d_model * self._dtype_bytes,
             [dt / self.n_stages] * self.n_stages,
             inject_t=self._ret_ready.get(mb, 0.0)
             if entries[0] is not None else 0.0, plane="decode")
-        self._observe_stages(dt, delays, obs.stalls)
+        self._observe_stages(dt, delays, link.stalls)
         if self.recorder is not None:
             # per-stage occupancy: which microbatch sat in each stage
             # slot this tick (-1 = bubble) — host ints from mb_assign
@@ -1030,12 +1049,13 @@ class PipelinedBackend(_SlotCacheBackend):
         self._entries = [None] + entries[:-1]
         if drained is None:
             return results
-        self._ret_ready[drained[0]] = obs.return_ready
+        self._ret_ready[drained[0]] = link.return_ready
         # §4.3 return link: token ids of the draining microbatch ride
         # back to the host injector — one batched (tokens, logprobs)
         # transfer per drained tick, not two separate syncs
-        # repro-audit: allow(host-sync) — intended §4.3 return-link sync, one batched transfer per drain
-        toks, lps = jax.device_get((toks, lps))
+        with obs.span("step.decode_readback", self.recorder):
+            # repro-audit: allow(host-sync) — intended §4.3 return-link sync, one batched transfer per drain
+            toks, lps = jax.device_get((toks, lps))
         return results + [DecodeResult(mb=drained[0], tokens=toks,
                                        logprobs=lps)]
 

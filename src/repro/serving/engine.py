@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.models.common import Runtime
 from repro.serving import kv_cache as kvc
@@ -710,17 +711,28 @@ class OfflineEngine:
         """One engine tick: reap finished, run the prefill phase (one
         budgeted chunk through the prefill plane, or the exact-length
         fallback admission), tick one microbatch through the backend.
-        Returns False when fully drained."""
+        Returns False when fully drained.  The tick is the ``sched.tick``
+        span; its phases are the finer ``sched.*`` spans."""
+        with obs.span("sched.tick", self.recorder):
+            return self._tick()
+
+    def _tick(self) -> bool:
+        rec = self.recorder
         t0 = time.perf_counter()
-        self._reap()
+        with obs.span("sched.reap", rec):
+            self._reap()
         tp = time.perf_counter()
         if self.chunked_prefill:
-            chunk = self._build_chunk()
-            for res in self.backend.prefill_step(chunk):
-                self._apply_prefill_result(res)
-            self._activate_ready()
+            with obs.span("sched.plan", rec):
+                chunk = self._build_chunk()
+            results = self.backend.prefill_step(chunk)
+            with obs.span("sched.prefill_results", rec):
+                for res in results:
+                    self._apply_prefill_result(res)
+                self._activate_ready()
         else:
-            self._admit()
+            with obs.span("sched.plan", rec):
+                self._admit()
         tp2 = time.perf_counter()
         self.stats.queue_depth = len(self.queue)
         # drained iff no slot is occupied (active, prefilling, or finished-
@@ -732,10 +744,6 @@ class OfflineEngine:
             self.stats.prefill_time_s += tp2 - tp
             self.stats.decode_time_s += tp - t0
             self.stats.wall_time_s += time.perf_counter() - t0
-            if self.recorder is not None:
-                self.recorder.step_phase("reap", t0, tp, self.stats.steps)
-                self.recorder.step_phase("prefill", tp, tp2,
-                                         self.stats.steps)
             if self.auditor is not None:
                 self.auditor.after_step()
             return False
@@ -753,13 +761,6 @@ class OfflineEngine:
         self.stats.wall_time_s += t1 - t0
         if self.slo is not None:
             self.slo.observe_tick(t1 - t0)
-        if self.recorder is not None:
-            # the stamps EngineStats uses anyway — no extra clock reads
-            # on the hot path beyond the one t1 above
-            step = self.stats.steps - 1
-            self.recorder.step_phase("reap", t0, tp, step)
-            self.recorder.step_phase("prefill", tp, tp2, step)
-            self.recorder.step_phase("decode", tp2, t1, step)
         if self.auditor is not None:
             self.auditor.after_step()
         return True
@@ -871,9 +872,11 @@ class OfflineEngine:
         if shared:
             self.stats.prefix_hits += 1
             self.stats.prefix_hit_tokens += seq.prefill_pos
+        now = time.perf_counter()
+        self.stats.admitted += 1
+        self.stats.queue_wait_s += now - seq.submit_time
         if self.recorder is not None:
             rid = seq.request.request_id
-            now = time.perf_counter()
             self.recorder.request_admit(rid, now)
             self.recorder.request_pages(rid, n_pages)
             if shared:
@@ -1174,8 +1177,9 @@ class OfflineEngine:
                                       self._row_sampling(lo, hi),
                                       active=mb_active)
         self.stats.swaps = self.backend.swap_count
-        for res in results:
-            self._apply_result(res)
+        with obs.span("sched.decode_results", self.recorder):
+            for res in results:
+                self._apply_result(res)
 
     def _apply_result(self, res: DecodeResult) -> None:
         """Book one drained microbatch tick (possibly for an earlier
@@ -1251,6 +1255,8 @@ class OfflineEngine:
             "decode_tokens": self.stats.decode_tokens,
             "total_tokens": self.stats.total_tokens,
             "finished": self.stats.finished_requests,
+            "admitted": self.stats.admitted,
+            "queue_wait_s": self.stats.queue_wait_s,
             "steps": self.stats.steps,
             "swaps": self.stats.swaps,
             "wall_time_s": self.stats.wall_time_s,

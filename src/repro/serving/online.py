@@ -41,6 +41,7 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Union
 
+from repro import obs
 from repro.serving.llm import LLM, EngineConfig, RequestOutput
 from repro.serving.request import SamplingParams, SequenceState, Status
 
@@ -248,10 +249,13 @@ class OnlineLLM:
         """One pump iteration: admit queued submissions, advance the
         engine one tick, deliver newly generated tokens to their streams.
         Returns True while any work remains."""
+        rec = self.engine.recorder
         with self._step_lock:
-            self._drain_inbox()
+            with obs.span("front.inbox", rec):
+                self._drain_inbox()
             alive = self.engine.step()
-            self._dispatch()
+            with obs.span("front.deliver", rec):
+                self._dispatch()
         with self._lock:
             return alive or bool(self._inbox)
 

@@ -167,6 +167,11 @@ class EngineStats:
     # prefill_tokens counts only actually-computed tokens)
     prefix_hits: int = 0
     prefix_hit_tokens: int = 0
+    # admission into a slot, and the seconds the admitted requests waited
+    # for it (admission minus engine submit), summed: their ratio is the
+    # mean queue wait
+    admitted: int = 0
+    queue_wait_s: float = 0.0
 
     @property
     def total_tokens(self) -> int:

@@ -102,6 +102,25 @@ def test_fixture_obs_hot_path_fires_twice():
     assert "host scalars" in dev.msg     # ... and what to record instead
 
 
+@pytest.mark.parametrize("case", ["tick-jit", "materialises"])
+def test_fixture_obs_span_is_a_recording_call(case):
+    """``obs.span(...)`` is a recording call: opened inside a tick-jit
+    body, or fed a device value in the hot path, it is flagged; a span
+    given only a name and the recorder is not."""
+    path = FIXTURES / "fixture_obs_span.py"
+    marks = _marks(path)
+    cfg = AuditConfig(hot_roots=["fixture_obs_span:hot_step"],
+                      traced_fns=["fixture_obs_span:tick_fn"])
+    vs = run_lint([path], config=cfg)
+    assert sorted((v.rule, v.line) for v in vs) == sorted(
+        ("obs-hot-path", ln) for ln in marks["obs-hot-path"])
+    hit = [v for v in vs if case in v.msg]
+    assert len(hit) == 1 and "obs.span" in hit[0].msg
+    in_jit_line, device_line = marks["obs-hot-path"]
+    assert hit[0].line == (in_jit_line if case == "tick-jit"
+                           else device_line)
+
+
 def test_suppression_with_reason_silences(tmp_path):
     f = tmp_path / "mod_sync.py"
     f.write_text(

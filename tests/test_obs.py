@@ -14,7 +14,7 @@ import pytest
 from conftest import tiny
 from repro.distributed.transport import SimulatedLinkTransport
 from repro.models import model as M
-from repro.obs import (Metrics, TraceRecorder, chrome_trace_events,
+from repro.obs import (Metrics, TraceRecorder, chrome_trace_events, span,
                        validate_chrome_trace, write_chrome_trace)
 from repro.obs.metrics import update_from_engine
 from repro.serving.engine import OfflineEngine, _resolve_trace
@@ -52,14 +52,16 @@ def test_request_table_bounded_evicts_finished_first():
 
 def test_recorder_event_shapes_export_and_ledger():
     rec = TraceRecorder()
-    rec.step_phase("decode", 1.0, 2.0, step=3)
+    with span("sched.tick", rec):
+        with span("kv.swap_out", rec, (("mb", 2), ("async", True))):
+            pass
+        with span("kv.swap_wait", rec, (("mb", 2),)):
+            pass
     rec.pipe_tick("decode", 0.0, 1.0, (0, -1))
     rec.link_send("decode", 0, 1024, 0.0, 0.5)
     rec.link_send("decode", 1, 64, 0.5, 0.6, return_trip=True)
     rec.tick_stall("decode", 0.25, 1.0)
     rec.stage_busy("decode", 1, 0.0, 0.5)
-    rec.offload_swap_out(2, 1.0, True)
-    rec.offload_swap_in(2, 1.0, 1.5)
     rec.prefix_event("hit", 7, 32, 1.0)
     rec.slo_budget(0.5, 16, 1.0)
     rec.fault("drop", 1.0, (("plane", "decode"), ("mb", 1)))
@@ -69,8 +71,8 @@ def test_recorder_event_shapes_export_and_ledger():
     trace = chrome_trace_events(rec)
     assert validate_chrome_trace(trace) == []
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"decode", "tick", "send", "return", "stall", "busy",
-            "swap_out", "swap_in", "prefix_hit", "slo_budget",
+    assert {"sched.tick", "tick", "send", "return", "stall", "busy",
+            "kv.swap_out", "kv.swap_wait", "prefix_hit", "slo_budget",
             "fault_drop", "reshard_drain"} <= names
 
 
@@ -257,8 +259,11 @@ def test_offline_request_traces_match_engine_stamps(rt):
         if eng.chunked_prefill:
             assert tr["chunks"] >= 1
     phases = [e for e in eng.recorder.events
-              if e.track == "engine" and e.kind == "span"]
-    assert {e.name for e in phases} >= {"reap", "prefill", "decode"}
+              if e.track == "sched" and e.kind == "span"]
+    assert {e.name for e in phases} >= {"sched.tick", "sched.reap",
+                                        "sched.plan",
+                                        "sched.prefill_results",
+                                        "sched.decode_results"}
     wall = chrome_trace_events(eng.recorder)
     assert validate_chrome_trace(wall) == []
 
