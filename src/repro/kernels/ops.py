@@ -8,10 +8,11 @@ of the kernel body) — numerically identical, structurally the same program
 
 The routing predicate is conservative: Pallas requires the head dim to be a
 multiple of the 128-lane register width for MXU efficiency (64 is accepted:
-it packs two heads per register row on v5e).  The paged kernel streams one
-KV head's ``(page_size, Dh)`` column slab of the ``(page, Hk·Dh)`` pool
-rows, so its lane dimension must be a multiple of 128 unless the pool holds
-a single KV head.
+it packs two heads per register row on v5e).  The paged kernel copies whole
+pages, viewed as ``(page_size·Hk, Dh)`` rows, with its own DMAs; a DMA
+slice's lane dimension must be a multiple of 128, so the paged route needs
+``Dh % 128 == 0`` whatever ``Hk`` is (a ``Dh`` of 64 goes to the oracle),
+and a ``page_size`` that is a multiple of 8.
 """
 
 from __future__ import annotations
@@ -58,11 +59,11 @@ def decode_attention(q, k_cache, v_cache, slot_pos, cur_pos, *,
 
 def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
                            window: int = 0, pages_per_block: int = 0):
-    """``pages_per_block=0`` autotunes the per-grid-step page count from
-    the ``(page_size, Dh, G)`` shape (see ``tuned_pages_per_block``)."""
+    """``pages_per_block=0`` autotunes the pages per DMA batch and compute
+    step from the pool's shape (see ``tuned_pages_per_block``)."""
     dh = q.shape[-1]
-    page_size, hk = k_pages.shape[1], k_pages.shape[2]
-    if _aligned(dh) and (dh % 128 == 0 or hk == 1) and page_size % 8 == 0:
+    page_size = k_pages.shape[1]
+    if dh % 128 == 0 and dh > 0 and page_size % 8 == 0:
         return _paged_pl(q, k_pages, v_pages, page_table, seq_lens,
                          window=window, pages_per_block=pages_per_block)
     return ref.paged_decode_attention_ref(q, k_pages, v_pages, page_table,

@@ -3,44 +3,39 @@
 This is the TPU adaptation of the paper's FlashInfer paged-KV decode path:
 the KV cache lives in a *page pool* (``(n_pages, page_size, Hk, Dh)``) and
 each sequence owns a list of pages (``page_table`` (B, max_pages)).  The
-kernel streams a sequence's pages into VMEM — the page indirection is
-resolved by the BlockSpec index_map reading the scalar-prefetched page table
-(``PrefetchScalarGridSpec``), so pages travel HBM→VMEM without a gather
-materialising the contiguous KV.
+kernel walks each row's live pages only, copying them from HBM into VMEM
+with its own DMAs; no gather materialises the contiguous KV.
 
-Layout/tuning (FlashInfer-style multi-page streaming):
-
-* The pool is viewed as ``(n_pages, page_size, Hk·Dh)`` (a free reshape)
-  and each KV block is one head's ``(page_size, Dh)`` slab of a page.  Its
-  last two block dimensions are then tile-legal on the TPU (``page_size``
-  a multiple of 8, ``Dh`` a multiple of 128); a ``(page_size, 1, Dh)``
-  block over the 4-D pool is not, since one head of ``Hk`` is neither a
-  multiple of 8 nor the whole axis.
-* Grid = ``(B, Hk, n_blocks)`` — one grid dimension per KV head so GQA
-  groups never share a softmax scratch, and the innermost dimension walks
-  *blocks* of ``pages_per_block`` pages.  Each grid step DMAs
-  ``pages_per_block`` pages and runs ONE online-softmax rescale over all of
-  them, amortising the rescale and the per-step DMA setup that a
-  one-page-per-step walk pays ``pages_per_block`` times.
-* ``pages_per_block`` is autotuned per ``(page_size, Dh, G)`` via
-  ``tuned_pages_per_block`` (overridable per call).
-* The running ``m``/``l`` statistics live in one fused ``(G, 2)`` VMEM
-  scratch (column 0 = running max, column 1 = running denominator) — one
-  buffer to initialise and one address stream instead of two.
-* With a sliding window, blocks entirely below the window are skipped
-  before the dot (``pl.when`` on the block-level live predicate), not
-  merely masked after it.
-
-Skipped-slot handling: table slots at or beyond ``ceil(seq_len/page_size)``
-carry no meaning, and earlier revisions clamped their *page id* to pool
-page 0 — issuing a (read-only, masked) DMA against whatever request owns
-page 0.  That aliasing assumption is gone: the index map now clamps the
-*slot* to the sequence's own last valid page, so masked grid steps only
-ever re-read a page the row already owns.  The one residual read outside a
-row's pages is the ``seq_len == 0`` row (no valid pages at all), which
-reads the page id in its own table slot 0 — the allocator zero-fills
-unused table rows, and pool page 0 is the allocator's reserved scratch
-page, never user data.
+* Grid = ``(B,)``: one step per row, no axis over table slots.  The page
+  table and ``seq_lens`` are scalar-prefetched into SMEM; the pools stay
+  in HBM (``memory_space=pl.ANY``) and are viewed as
+  ``(n_pages, page_size·Hk, Dh)``, which merges two adjacent dimensions
+  of the stored layout and moves no data.
+* A row's live pages run from the first page its window can see
+  (``max(seq_len - window, 0) // page_size``, or 0 with no window) to
+  ``ceil(seq_len / page_size)``.  A ``fori_loop`` walks them in blocks of
+  ``pages_per_block`` pages; each page moves as ONE DMA of all its KV
+  heads (``page_size·Hk·Dh`` elements) for K and one for V.  Table slots
+  past the row's live pages are never read, nor is any pool page the row
+  does not own.
+* DMAs are double-buffered: block i+1 is started before block i is
+  computed, and a row's last block starts the next row's first one, so
+  only the first row of a call waits for its first DMA.  Grid steps
+  therefore run in order (``"arbitrary"``), and the buffer slot of the
+  next block lives in SMEM across steps.
+* A block's keys are ``(pages_per_block·page_size·Hk, Dh)`` rows, token
+  major.  All ``H = Hk·G`` queries attend to them in one product, and a
+  block-diagonal mask keeps each query to its own KV head; the same mask
+  drops the tokens past ``seq_len`` (only the last page is partial) and
+  those below the window.  The wasted products cost nothing visible at
+  decode's tiny arithmetic intensity.
+* The online softmax keeps its running ``m``/``l`` statistics per query
+  head in one fused ``(H, 2)`` VMEM scratch (column 0 = running max,
+  column 1 = running denominator).
+* A row with ``seq_len == 0`` issues no DMA and writes zeros, as the
+  oracle defines for it.
+* ``pages_per_block`` (pages per DMA batch and compute step) is autotuned
+  per shape via ``tuned_pages_per_block`` (overridable per call).
 
 Oracle: ``repro.kernels.ref.paged_decode_attention_ref``.
 """
@@ -59,75 +54,119 @@ from repro.kernels import default_interpret
 
 NEG_INF = -1e30
 
-# Autotuned block choices, keyed (page_size, Dh, G).  Values picked so one
-# grid step streams a few hundred KV tokens (amortising the rescale) while
-# the K+V block pair stays well inside VMEM at bf16.  Shapes not listed
-# fall back to the same ~512-token target with a VMEM-budget cap.
-_TUNED_PPB = {
-    (8, 64, 1): 8, (8, 64, 2): 8, (8, 64, 4): 8, (8, 64, 8): 4,
-    (8, 128, 1): 8, (8, 128, 2): 4, (8, 128, 4): 4, (8, 128, 8): 4,
-    (16, 64, 1): 4, (16, 64, 2): 4, (16, 64, 4): 4, (16, 64, 8): 2,
-    (16, 128, 1): 4, (16, 128, 2): 4, (16, 128, 4): 2, (16, 128, 8): 2,
-    (32, 64, 1): 2, (32, 64, 4): 2, (32, 128, 1): 2, (32, 128, 4): 2,
-    (64, 64, 1): 2, (64, 128, 1): 1, (128, 64, 1): 1, (128, 128, 1): 1,
-}
-_PPB_VMEM_CAP = 128 * 1024        # bytes per K/V block pair (bf16)
+# Pages per block, keyed by the bytes of one whole page (every KV head),
+# from a sweep on a TPU v5e at the benchmark cells' shapes: 16 KiB pages
+# (page 16, Hk 4, Dh 128, bf16) and 32 KiB pages (Hk 8) both run fastest
+# at 16 pages a block.  Other pages aim at 256 tokens a block.
+_TUNED_PPB = {16 * 1024: 16, 32 * 1024: 16}
+_PPB_TARGET_TOKENS = 256
+# VMEM for the K and V blocks, both buffers: ppb whole pages x 2 x 2
+_PPB_VMEM_CAP = 2 * 1024 * 1024
 
 
-def tuned_pages_per_block(page_size: int, dh: int, g: int) -> int:
-    """Pages streamed per grid step for a ``(page_size, Dh, G)`` shape."""
-    ppb = _TUNED_PPB.get((page_size, dh, g))
-    if ppb is None:
-        target = 512 if dh <= 64 else 256          # KV tokens per step
-        ppb = max(1, target // page_size)
-        while ppb > 1 and 2 * ppb * page_size * dh * 2 > _PPB_VMEM_CAP:
-            ppb //= 2
+def tuned_pages_per_block(page_size: int, dh: int, hk: int = 1,
+                          itemsize: int = 2) -> int:
+    """Pages per DMA batch and compute step for a pool of
+    ``(page_size, Hk, Dh)`` pages of ``itemsize``-byte elements."""
+    page_bytes = page_size * hk * dh * itemsize
+    ppb = _TUNED_PPB.get(page_bytes, max(1, _PPB_TARGET_TOKENS // page_size))
+    while ppb > 1 and ppb * page_bytes * 2 * 2 > _PPB_VMEM_CAP:
+        ppb //= 2
     return ppb
 
 
-def _paged_kernel(pt_ref, len_ref, q_ref, *refs, page_size: int,
-                  g: int, window: int, ppb: int, n_blocks: int):
-    ks = refs[:ppb]
-    vs = refs[ppb:2 * ppb]
-    o_ref = refs[2 * ppb]
-    acc_ref, ml_ref = refs[2 * ppb + 1], refs[2 * ppb + 2]
-
+def _paged_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, slot_ref, acc_ref, ml_ref, *,
+                  page_size: int, hk: int, g: int, window: int, ppb: int,
+                  max_pages: int):
     b = pl.program_id(0)
-    blk = pl.program_id(2)
+    n_rows = pl.num_programs(0)
+    rows = page_size * hk                      # buffer rows of one page
 
-    @pl.when(blk == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        ml_ref[:, 0] = jnp.full((g,), NEG_INF, jnp.float32)
-        ml_ref[:, 1] = jnp.zeros((g,), jnp.float32)
+    def span(row):
+        """First live page of ``row``, its page count and its blocks."""
+        seq_len = len_ref[row]
+        hi = jnp.minimum((seq_len + page_size - 1) // page_size, max_pages)
+        lo = (jnp.maximum(seq_len - window, 0) // page_size if window > 0
+              else jnp.int32(0))
+        return lo, hi, (hi - lo + ppb - 1) // ppb
 
-    seq_len = len_ref[b]                       # tokens in cache (incl. current)
-    n_pages = (seq_len + page_size - 1) // page_size
-    base = blk * ppb                           # first page slot of this block
-    live = base < n_pages
-    if window > 0:
-        # first in-window token is seq_len - window; blocks whose last page
-        # ends before it contribute nothing — skip them before the dot.
-        lo_page = jnp.maximum(seq_len - window, 0) // page_size
-        live = jnp.logical_and(live, base + ppb > lo_page)
+    def copies(row, blk, slot, fn):
+        """Apply ``fn`` to the K and V copies of each live page of block
+        ``blk`` of ``row``, whose buffers are ``slot``."""
+        lo, hi, _ = span(row)
+        first = lo + blk * ppb
 
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0]                        # (G, Dh)
-        if ppb == 1:
-            k = ks[0][0]                       # (page_size, Dh)
-            v = vs[0][0]
-        else:
-            k = jnp.concatenate([kr[0] for kr in ks], axis=0)
-            v = jnp.concatenate([vr[0] for vr in vs], axis=0)
+        def each(j, carry):
+            page = pt_ref[row, first + j]
+            fn(pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, j],
+                                     sems.at[0, slot]))
+            fn(pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, j],
+                                     sems.at[1, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(ppb, hi - first), each, 0)
+
+    def start(row, blk, slot):
+        copies(row, blk, slot, lambda c: c.start())
+
+    def wait(row, blk, slot):
+        copies(row, blk, slot, lambda c: c.wait())
+
+    def start_next_row(slot):
+        @pl.when(b + 1 < n_rows)
+        def _():
+            @pl.when(span(b + 1)[2] > 0)
+            def _():
+                start(b + 1, 0, slot)
+
+    @pl.when(b == 0)
+    def _prime():
+        slot_ref[0] = 0
+
+        @pl.when(span(0)[2] > 0)
+        def _():
+            start(0, 0, 0)
+
+    seq_len = len_ref[b]
+    lo, _, n_blocks = span(b)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    ml_ref[:, 0] = jnp.full((hk * g,), NEG_INF, jnp.float32)
+    ml_ref[:, 1] = jnp.zeros((hk * g,), jnp.float32)
+
+    q = q_ref[0]                               # (H, Dh)
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    width = ppb * rows
+    col = jax.lax.broadcasted_iota(jnp.int32, (hk * g, width), 1)
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (hk * g, width), 0) // g
+    same_head = (col % hk) == row_head
+    tok_in_block = col // hk
+    # token of each buffer row, for the values: the pages past a row's
+    # last one in a partial block hold what an earlier block (or nothing)
+    # left there, and 0 x NaN would reach the output
+    v_tok_in_block = jax.lax.broadcasted_iota(jnp.int32, (width, 1), 0) // hk
+
+    def body(i, slot):
+        nxt = 1 - slot
+
+        @pl.when(i + 1 < n_blocks)
+        def _():
+            start(b, i + 1, nxt)
+
+        @pl.when(i + 1 == n_blocks)
+        def _():
+            start_next_row(nxt)
+
+        wait(b, i, slot)
+        k = k_buf[slot].reshape(width, k_buf.shape[-1])   # (T·Hk, Dh)
+        v = v_buf[slot].reshape(width, v_buf.shape[-1])
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        s = s * (1.0 / (q_ref.shape[-1] ** 0.5))          # (G, ppb·page)
-
-        span = ppb * page_size
-        tok = base * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (g, span), 1)
-        mask = tok < seq_len
+        s = s * scale                                     # (H, T·Hk)
+        base = (lo + i * ppb) * page_size
+        v = jnp.where(base + v_tok_in_block < seq_len, v, 0)
+        tok = base + tok_in_block
+        mask = same_head & (tok < seq_len)
         if window > 0:
             mask &= tok > seq_len - 1 - window
         s = jnp.where(mask, s, NEG_INF)
@@ -142,11 +181,15 @@ def _paged_kernel(pt_ref, len_ref, q_ref, *refs, page_size: int,
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         ml_ref[:, 0] = m_cur
+        return nxt
 
-    @pl.when(blk == n_blocks - 1)
-    def _finalize():
-        l = jnp.maximum(ml_ref[:, 1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    @pl.when(n_blocks == 0)
+    def _empty():
+        start_next_row(slot_ref[0])
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_blocks, body, slot_ref[0])
+    l = jnp.maximum(ml_ref[:, 1], 1e-30)
+    o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -162,8 +205,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     k/v_pages  (P, page, Hk, Dh)  shared page pool
     page_table (B, max_pages)     page ids per sequence (row-major in time)
     seq_lens   (B,)               tokens present per sequence
-    pages_per_block               KV pages streamed per grid step
-                                  (0 = autotuned per (page_size, Dh, G))
+    pages_per_block               KV pages per DMA batch and compute step
+                                  (0 = autotuned per shape)
     -> (B, H, Dh)
     """
     b, h, dh = q.shape
@@ -171,53 +214,41 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     g = h // hk
     max_pages = page_table.shape[1]
 
-    ppb = pages_per_block or tuned_pages_per_block(page_size, dh, g)
+    ppb = pages_per_block or tuned_pages_per_block(
+        page_size, dh, hk, k_pages.dtype.itemsize)
     ppb = max(1, min(ppb, max_pages))
-    n_blocks = (max_pages + ppb - 1) // ppb
+    rows = page_size * hk
 
-    qr = q.reshape(b, hk, g, dh)
-    # defensive pool-range clamp (matches the oracle); the slot clamp in
-    # the index maps below is what keeps skipped steps on the row's pages
+    # defensive pool-range clamp (matches the oracle)
     pt = jnp.clip(page_table, 0, n_pool - 1).astype(jnp.int32)
-
-    def _kv_map(j):
-        def index_map(bi, hi, blki, pt_ref, len_ref):
-            # clamp the slot to this row's own last valid page: masked
-            # grid steps re-read a page the row owns instead of page 0
-            n_pages = (len_ref[bi] + page_size - 1) // page_size
-            last = jnp.minimum(jnp.maximum(n_pages - 1, 0), max_pages - 1)
-            slot = jnp.minimum(blki * ppb + j, last)
-            return (pt_ref[bi, slot], 0, hi)
-        return index_map
-
-    kv_specs = [pl.BlockSpec((1, page_size, dh), _kv_map(j))
-                for j in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, n_blocks),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh),
-                         lambda bi, hi, pi, pt_ref, len_ref: (bi, hi, 0, 0)),
-            *kv_specs,
-            *kv_specs,
+            pl.BlockSpec((1, h, dh), lambda bi, pt_ref, len_ref: (bi, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, g, dh),
-            lambda bi, hi, pi, pt_ref, len_ref: (bi, hi, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dh),
+                               lambda bi, pt_ref, len_ref: (bi, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, dh), jnp.float32),
-            pltpu.VMEM((g, 2), jnp.float32),      # fused (m, l) statistics
+            pltpu.VMEM((2, ppb, rows, dh), k_pages.dtype),
+            pltpu.VMEM((2, ppb, rows, dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),      # (K/V, buffer slot)
+            pltpu.SMEM((1,), jnp.int32),          # slot of the next block
+            pltpu.VMEM((h, dh), jnp.float32),
+            pltpu.VMEM((h, 2), jnp.float32),      # fused (m, l) statistics
         ],
     )
-    kernel = functools.partial(_paged_kernel, page_size=page_size,
+    kernel = functools.partial(_paged_kernel, page_size=page_size, hk=hk,
                                g=g, window=window, ppb=ppb,
-                               n_blocks=n_blocks)
-    out = pl.pallas_call(
+                               max_pages=max_pages)
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, g, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=default_interpret() if interpret is None else interpret,
-    )(pt, seq_lens.astype(jnp.int32), qr,
-      *([k_pages.reshape(n_pool, page_size, hk * dh)] * ppb),
-      *([v_pages.reshape(n_pool, page_size, hk * dh)] * ppb))
-    return out.reshape(b, h, dh)
+    )(pt, seq_lens.astype(jnp.int32), q,
+      k_pages.reshape(n_pool, rows, dh), v_pages.reshape(n_pool, rows, dh))

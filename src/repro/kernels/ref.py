@@ -27,6 +27,7 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, seq_lens, *,
     """Gather-then-attend oracle for the paged decode kernel.
 
     Shapes as in ``repro.kernels.paged_attention.paged_decode_attention``.
+    A row with ``seq_len == 0`` has nothing to attend to and reads zeros.
     """
     b, h, dh = q.shape
     page_size = k_pages.shape[1]
@@ -38,4 +39,5 @@ def paged_decode_attention_ref(q, k_pages, v_pages, page_table, seq_lens, *,
     pos = jnp.arange(c)[None]                            # logical positions
     slot_pos = jnp.where(pos < seq_lens[:, None], pos, -1).astype(jnp.int32)
     cur = (seq_lens - 1).astype(jnp.int32)
-    return decode_attention_ref(q, k, v, slot_pos, cur, window=window)
+    out = decode_attention_ref(q, k, v, slot_pos, cur, window=window)
+    return jnp.where((seq_lens > 0)[:, None, None], out, 0).astype(out.dtype)

@@ -44,8 +44,8 @@ class Runtime:
     train_style: str = "sp"           # sp (TP+seq-parallel) | zero3 (batch
                                       # over data+model, weights gathered)
     kv_dtype: str = "bf16"            # bf16 | int8 (quantized KV cache)
-    attn_pages_per_block: int = 0     # paged-decode KV pages per grid step
-                                      # (0 = autotuned per (page, Dh, G))
+    attn_pages_per_block: int = 0     # paged-decode KV pages per block
+                                      # (0 = autotuned per pool shape)
 
     def replace(self, **kw) -> "Runtime":
         return dataclasses.replace(self, **kw)

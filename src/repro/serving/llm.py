@@ -96,8 +96,8 @@ class EngineConfig:
     # environment variable (the test suite defaults it on); True/False
     # force it either way.  Host-side bookkeeping only — no device syncs.
     strict: Optional[bool] = None
-    # paged-decode attention: KV pages streamed per Pallas grid step
-    # (0 = autotuned from the (page_size, Dh, G) shape; see
+    # paged-decode attention: KV pages per DMA batch and compute step
+    # (0 = autotuned from the bytes of one whole page; see
     # repro.kernels.paged_attention.tuned_pages_per_block)
     attn_pages_per_block: int = 0
     # decode-tick sampling epilogue: replace the full-vocab sort with one

@@ -7,13 +7,19 @@ mode on CPU.  The full cross product runs under ``-m slow``; tier-1 runs
 a seeded subsample so every axis stays exercised per-commit without the
 interpret-mode bill.
 
-Also pins two properties the sweeps alone can't see:
+Ragged cases mix rows of length 0, 1, page_size, page_size + 1 and the
+table's whole extent in one batch, at GQA groups 1-16, 1-8 KV heads and
+head dims 64-256, with live page counts that ``pages_per_block`` does not
+divide and windows whose first visible token falls inside a page.
+
+Also pins properties the sweeps alone can't see:
 
 * ``pages_per_block`` is a pure schedule knob — every ppb choice must
   match the oracle on the same inputs;
-* unowned pool pages are never read: NaN-poisoning every page outside
-  the rows' own page-table ranges must leave the output *bitwise*
-  unchanged (the index-map clamp of ISSUE 8 satellite b).
+* only a row's live pages are read: NaN-poisoning every pool page outside
+  the rows' live page ranges, or pointing the table slots past each row's
+  live pages at other rows' pages, must leave the output *bitwise*
+  unchanged.
 """
 
 import itertools
@@ -48,6 +54,19 @@ FLASH_AXES = list(itertools.product(
 ))
 
 
+# (page_size, Dh, G, window, dtype, "ragged", Hk, pages_per_block)
+RAGGED_CASES = [
+    (16, 128, 8, 0, jnp.bfloat16, "ragged", 4, 0),     # yi-9b's shape
+    (16, 128, 7, 1, jnp.bfloat16, "ragged", 8, 2),     # yi-34b's shape
+    (8, 64, 2, 0, jnp.float32, "ragged", 1, 2),
+    (8, 256, 16, 1, jnp.float32, "ragged", 1, 4),
+    (8, 128, 1, 0, jnp.float32, "ragged", 8, 3),
+    (16, 128, 2, 1, jnp.float32, "ragged", 4, 4),
+    (8, 256, 7, 0, jnp.bfloat16, "ragged", 4, 1),
+    (16, 64, 16, 1, jnp.bfloat16, "ragged", 1, 3),
+]
+
+
 def _subsample(axes, n, seed):
     rng = np.random.RandomState(seed)
     idx = rng.choice(len(axes), size=min(n, len(axes)), replace=False)
@@ -76,14 +95,26 @@ def _tol(dtype):
 # ---------------------------------------------------------------- paged --
 
 
+def _lens(edge, page, smax, win_sel):
+    if edge == "ragged":
+        # empty rows first, inside and last; five pages at the most
+        if win_sel == 0:
+            return [0, page + 1, smax, 1, 0, page]
+        return [page + 1, 0, smax, 1, page, 0]
+    by_edge = {"one": 1, "page-1": page - 1, "page": page, "max": smax}
+    # row 0 sits at the edge; row 1 at an unrelated interior length
+    return [by_edge[edge], min(smax, page + 3)]
+
+
 def _run_paged(case, pages_per_block=0):
-    page, dh, g, win_sel, dtype, edge = case
-    maxp, hk = 4, 2
+    page, dh, g, win_sel, dtype, edge, *rest = case
+    hk, pages_per_block = rest if rest else (2, pages_per_block)
+    maxp = 5 if edge == "ragged" else 4
     h = hk * g
     smax = page * maxp
-    lens_by_edge = {"one": 1, "page-1": page - 1, "page": page, "max": smax}
     window = 0 if win_sel == 0 else page + page // 2
-    b = 2
+    lens = jnp.asarray(_lens(edge, page, smax, win_sel), jnp.int32)
+    b = lens.shape[0]
     npool = 1 + b * maxp            # page 0 reserved scratch, no sharing
     ks = jax.random.split(jax.random.PRNGKey(hash(case) % (2 ** 31)), 4)
     q = jax.random.normal(ks[0], (b, h, dh), dtype)
@@ -91,9 +122,6 @@ def _run_paged(case, pages_per_block=0):
     vp = jax.random.normal(ks[2], (npool, page, hk, dh), dtype)
     # disjoint per-row page ranges so poisoning "unowned" is well-defined
     pt = jnp.arange(1, 1 + b * maxp, dtype=jnp.int32).reshape(b, maxp)
-    # row 0 sits at the edge; row 1 at an unrelated interior length
-    lens = jnp.asarray([lens_by_edge[edge],
-                        min(smax, page + 3)], jnp.int32)
     out = paged_decode_attention(q, kp, vp, pt, lens, window=window,
                                  pages_per_block=pages_per_block,
                                  interpret=True)
@@ -112,7 +140,8 @@ def test_paged_matrix_full(case):
     _run_paged(case)
 
 
-@pytest.mark.parametrize("case", _subsample(PAGED_AXES, 14, seed=0x5EED))
+@pytest.mark.parametrize("case", _subsample(PAGED_AXES, 14, seed=0x5EED) +
+                         RAGGED_CASES)
 def test_paged_matrix_sample(case):
     _run_paged(case)
 
@@ -125,12 +154,16 @@ def test_paged_ppb_is_pure_schedule(ppb):
 
 
 def test_paged_tuned_ppb_table_sane():
-    for page, dh, g in itertools.product((8, 16, 32, 64), (64, 128, 256),
-                                         (1, 2, 4, 8)):
-        ppb = tuned_pages_per_block(page, dh, g)
-        assert ppb >= 1, (page, dh, g)
-        # fused scratch + ppb pages of K and V must respect the VMEM cap
-        assert ppb * page * dh * 2 * 4 <= 512 * 1024, (page, dh, g, ppb)
+    for page, dh, hk, itemsize in itertools.product(
+            (8, 16, 32, 64), (64, 128, 256), (1, 2, 4, 8), (2, 4)):
+        ppb = tuned_pages_per_block(page, dh, hk, itemsize)
+        assert ppb >= 1, (page, dh, hk, itemsize)
+        # a block holds whole pages (every KV head); K and V each have
+        # two buffers, and all four must fit the VMEM cap
+        buffers, kv = 2, 2
+        page_bytes = page * hk * dh * itemsize
+        assert ppb * page_bytes * buffers * kv <= 2 * 1024 * 1024, \
+            (page, dh, hk, itemsize, ppb)
 
 
 def test_paged_ignores_unowned_pool_pages_bitwise():
@@ -153,6 +186,38 @@ def test_paged_ignores_unowned_pool_pages_bitwise():
     out_poison = paged_decode_attention(q, jnp.asarray(poison),
                                         jnp.asarray(poison_v), pt, lens,
                                         window=window, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out_clean),
+                                  np.asarray(out_poison))
+
+
+def test_paged_ignores_table_slots_past_live_bitwise():
+    """Point every table slot past a row's live pages at another row's
+    live page, and NaN-poison every pool page no row owns: the output must
+    be bitwise identical to the clean run, so those slots are never read
+    (nor anything they point at, which a masked read would hide)."""
+    case = (8, 128, 2, 1, jnp.float32, "ragged", 2, 2)
+    out_clean, (q, kp, vp, pt, lens, window) = _run_paged(case)
+    page = kp.shape[1]
+    pt_np = np.asarray(pt).copy()
+    live = [-(-int(n) // page) for n in np.asarray(lens)]
+    owned = {int(pt_np[r, j]) for r in range(len(live))
+             for j in range(live[r])}
+    for r in range(len(live)):
+        others = [int(pt_np[o, j]) for o in range(len(live)) if o != r
+                  for j in range(live[o])]
+        for j in range(live[r], pt_np.shape[1]):
+            pt_np[r, j] = others[j % len(others)]
+    poison = np.asarray(kp).copy()
+    poison_v = np.asarray(vp).copy()
+    for p in range(kp.shape[0]):
+        if p not in owned:
+            poison[p] = np.nan
+            poison_v[p] = np.nan
+    out_poison = paged_decode_attention(q, jnp.asarray(poison),
+                                        jnp.asarray(poison_v),
+                                        jnp.asarray(pt_np), lens,
+                                        window=window, pages_per_block=2,
+                                        interpret=True)
     np.testing.assert_array_equal(np.asarray(out_clean),
                                   np.asarray(out_poison))
 

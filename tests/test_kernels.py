@@ -50,6 +50,10 @@ PAGED_SWEEP = [
     (2, 4, 4, 64, 8, 5, 32, 20),
     (1, 16, 2, 64, 8, 8, 64, 0),
     (4, 2, 2, 128, 32, 2, 8, 0),
+    (3, 56, 8, 128, 16, 6, 40, 0),       # GQA group 7, eight KV heads
+    (2, 16, 1, 256, 8, 5, 16, 20),       # group 16, MQA, head dim 256
+    (4, 8, 8, 128, 8, 7, 40, 0),         # group 1 (MHA)
+    (3, 32, 4, 128, 16, 5, 24, 24),      # group 8, window inside a page
 ]
 
 

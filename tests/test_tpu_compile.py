@@ -4,7 +4,9 @@ The TPU compiler is installed even where no chip is attached: it compiles
 for a topology that is described, not present, and refuses what the chip
 would refuse (blocks that are not tile-legal, too much VMEM).  Interpret
 mode cannot catch either, so these compiles guard the kernels at yi-9b's
-published widths (32 query and 4 KV heads of 128, bf16).
+published widths (32 query and 4 KV heads of 128, bf16), and the paged
+kernel at the benchmark cells' decode shapes (yi-9b's, and yi-34b's 56
+query and 8 KV heads).
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process at a time may load the TPU library, and pytest-xdist
@@ -61,6 +63,32 @@ def test_paged_decode_compiles_for_v5e(one_chip, page_size, pages_per_block):
                         _shape(one_chip, (B, MAX_PAGES), jnp.int32),
                         _shape(one_chip, (B,), jnp.int32)).compile()
     _assert_kernel(compiled)
+
+
+# (B, H, Hk, max_pages, pool pages) of the benchmark's cells, page 16
+CELL_SHAPES = {"yi9b": (28, 32, 4, 128, 3413),
+               "yi34b": (24, 56, 8, 228, 4096)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_decode_compiles_for_v5e_at_cell_shapes(one_chip, cell):
+    """The benchmark cells' decode shapes lower through Mosaic, and the
+    pool reaches the kernel as a view of its stored layout: no copy or
+    relayout of a ``(P, page, Hk, Dh)`` pool precedes the custom call."""
+    b, h, hk, max_pages, n_pool = CELL_SHAPES[cell]
+    kv = _shape(one_chip, (n_pool, 16, hk, DH), jnp.bfloat16)
+    fn = jax.jit(lambda q, k, v, pt, sl: paged_decode_attention(
+        q, k, v, pt, sl, interpret=False))
+    compiled = fn.lower(_shape(one_chip, (b, h, DH), jnp.bfloat16), kv, kv,
+                        _shape(one_chip, (b, max_pages), jnp.int32),
+                        _shape(one_chip, (b,), jnp.int32)).compile()
+    _assert_kernel(compiled)
+    pool = f"bf16[{n_pool},"
+    moved = [line for line in compiled.as_text().splitlines()
+             if pool in line and
+             any(f" {op}(" in line for op in ("copy", "copy-start",
+                                               "reshape", "transpose"))]
+    assert not moved, moved
 
 
 def test_flash_prefill_compiles_for_v5e(one_chip):
