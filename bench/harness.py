@@ -23,7 +23,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from bench import loadgen, work
-from bench.model import program_config, program_runtime
+from bench.model import program_runtime
 
 
 @dataclass
@@ -85,6 +85,8 @@ def engine_config(cell, seed: int):
     from repro.serving.kv_cache import PoolConfig
     from repro.serving.llm import EngineConfig
     e = cell.cell["engine"]
+    pipe = {"n_stages": e["n_stages"]} if e["backend"] == "pipelined" \
+        and "n_stages" in e else {}
     pool = PoolConfig(page_size=e["page_size"],
                       n_local_pages=e["n_local_pages"],
                       n_global_pages=e["n_global_pages"],
@@ -94,7 +96,37 @@ def engine_config(cell, seed: int):
         pool=pool, offload=e["offload"], backend=e["backend"],
         seed=seed % 2 ** 31, prefill_chunk=e["prefill_chunk"],
         max_prefill_tokens_per_tick=e["max_prefill_tokens_per_tick"],
-        prefill_mode="chunked", strict=False)
+        prefill_mode="chunked", strict=False, **pipe)
+
+
+def placement(cell, devs):
+    """Where the cell's backend keeps the weights, as a map from the tree
+    of their shapes to their shardings: on the first device, or split
+    over the stages as the pipelined backend places them."""
+    import jax
+    ec = engine_config(cell, 0)
+    if ec.backend != "pipelined":
+        one = jax.sharding.SingleDeviceSharding(devs[0])
+        return lambda tree: one
+    from repro.core import pipeline as PL
+    return PL.stage_shardings(cell.block.program_config(cell.config),
+                              PL.stage_mesh(ec.n_stages), ec.n_stages)
+
+
+class Stages:
+    """The rows each pipeline stage decodes in one backend tick: the
+    injected microbatch enters stage 0 and every earlier injection moves
+    one stage down (the circular schedule).  The local backend is one
+    stage."""
+
+    def __init__(self, n_stages: int):
+        self.held: List[Optional[list]] = [None] * n_stages
+
+    def step(self, lens: list) -> List[Optional[list]]:
+        """The stages' rows in the backend tick that injected ``lens``
+        (empty: nothing live)."""
+        self.held = [lens or None] + self.held[:-1]
+        return list(self.held)
 
 
 class Harness:
@@ -104,13 +136,19 @@ class Harness:
         from repro.serving.llm import LLM
         from repro.serving.online import OnlineLLM
         conf = cell.config
-        self.cell, self.conf = cell, conf
+        self.cell, self.conf, self.block = cell, conf, cell.block
         ec = engine_config(cell, seed)
-        self.llm = LLM(program_config(conf), rt=program_runtime(conf),
-                       config=ec, params=params)
+        self.llm = LLM(self.block.program_config(conf),
+                       rt=program_runtime(conf), config=ec, params=params)
         self.online = OnlineLLM(llm=self.llm)
         self.engine = self.llm.engine
         self.n_slots = ec.mb_size * ec.num_microbatches
+        n_stages = ec.n_stages if ec.backend == "pipelined" else 1
+        self.stages = Stages(n_stages)
+        # a stage's decode reads the positions its microbatch had when it
+        # was injected; the local backend has advanced them by the tick's
+        # end, a deeper pipe drains the microbatch only ticks later
+        self.lag = 0 if n_stages == 1 else 1
         self.gen = loadgen.requests(cell.traffic, slots=self.n_slots,
                                     seed=seed, vocab=int(conf["vocab_size"]))
         self.live: Dict[int, ReqRec] = {}
@@ -157,8 +195,9 @@ class Harness:
             self.online.step()
         with jax.profiler.TraceAnnotation("readback"):
             finished = self._readback()
+        decoded = self._decoded(steps0)
         if win is not None:
-            self._book(win, before, steps0)
+            self._book(win, before, decoded)
             win.pages_used.append(self._pages_used_frac())
         if self.refill:
             with jax.profiler.TraceAnnotation("harness.refill"):
@@ -181,9 +220,24 @@ class Harness:
                 finished += 1
         return finished
 
-    def _book(self, win: Window, before: dict, steps0: int) -> None:
-        """Model operations of this tick, and the paged kernel's work."""
-        eng, conf = self.engine, self.conf
+    def _decoded(self, steps0: int) -> Optional[tuple]:
+        """After an engine tick: the context of each row the tick injected
+        (itself included), and the rows of every stage in the backend tick
+        it ran; None where the engine did not tick."""
+        eng = self.engine
+        if eng.stats.steps == steps0:
+            return None
+        mb = steps0 % eng.num_microbatches
+        lo, hi = mb * eng.mb_size, (mb + 1) * eng.mb_size
+        lens = [int(eng.cur_pos[s]) + self.lag for s in range(lo, hi)
+                if eng.active[s]]
+        return lens, self.stages.step(lens)
+
+    def _book(self, win: Window, before: dict, decoded) -> None:
+        """Model operations of this tick, and the paged kernel's work: each
+        decoded token's operations once, when its microbatch is injected,
+        and each stage's kernel calls in the backend tick that runs them."""
+        conf, block = self.conf, self.block
         for rid, pos0 in before.items():
             rec = self.live.get(rid) or next(
                 r for r in self.all if r.stream.request_id == rid)
@@ -192,22 +246,24 @@ class Harness:
                 continue
             pos1 = min(seq.prefill_pos, seq.prompt_len)
             if pos1 > pos0:
-                win.model_flops += work.prefill_flops(
+                win.model_flops += block.prefill_flops(
                     conf, pos0, pos1, last=pos1 == seq.prompt_len)
-        if eng.stats.steps == steps0:
+        if decoded is None:
             return
-        mb = steps0 % eng.num_microbatches
-        lo, hi = mb * eng.mb_size, (mb + 1) * eng.mb_size
-        lens = [int(eng.cur_pos[s]) for s in range(lo, hi) if eng.active[s]]
-        if not lens:
-            return
-        win.model_flops += sum(work.decode_token_flops(conf, n)
+        lens, held = decoded
+        win.model_flops += sum(block.decode_token_flops(conf, n)
                                for n in lens)
-        f, b = work.paged_decode_call(conf, lens)
         layers = int(conf["num_hidden_layers"])
-        win.paged_calls += layers
-        win.paged_roof_s += layers * work.roofline_seconds(
-            f, b, self.peaks)[0]
+        n = len(self.stages.held)
+        per = [layers // n] * n
+        per[-1] += layers % n          # the epilogue runs after the last
+        for rows, k in zip(held, per):
+            if not rows:
+                continue
+            for f, b, count in block.paged_decode_calls(conf, rows, k):
+                win.paged_calls += count
+                win.paged_roof_s += count * work.roofline_seconds(
+                    f, b, self.peaks)[0]
 
     # -- phases ---------------------------------------------------------------
 

@@ -1,8 +1,9 @@
 """Kernels: the Pallas paged decode-attention kernel's share of its
 roofline.  For every call in the window the least time is the larger of
 its operations over the bf16 peak and its bytes over the HBM bandwidth
-(``bench.work.paged_decode_call``, from the live lengths); their sum is
-divided by the kernel's device time in the trace."""
+(the block's ``paged_decode_calls`` in ``bench/blocks/<block>.py``, from
+the live lengths); their sum is divided by the kernel's device time in
+the trace."""
 
 from bench.trace import is_paged_kernel
 

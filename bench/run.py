@@ -133,9 +133,9 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
     log = harness.CompileLog()
     conf = cell.config
     stamps.append(("chip", time.perf_counter()))
-    params = jax.block_until_ready(
-        model.make_params(conf, seed, device=devs[0]))
-    model.check_layout(conf, params)
+    params = jax.block_until_ready(model.make_params(
+        cell.block, conf, seed, place=harness.placement(cell, devs)))
+    model.check_layout(cell.block, conf, params)
     stamps.append(("weights", time.perf_counter()))
     h = harness.Harness(cell, params, seed)
     stamps.append(("engine", time.perf_counter()))
@@ -182,7 +182,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *,
                           length=lambda s: len(s.prompt) + len(s.tokens),
                           greedy=lambda s: s.greedy)
     t_ref = time.perf_counter()
-    ref = reference.Reference(conf, params)
+    ref = reference.Reference(cell.block, conf, params)
     numbers = check.readings(ref, sample, kinds)
     say(f"reference over {len(sample)} requests ({numbers['tokens']} "
         f"tokens, {numbers['greedy_tokens']} greedy) took "

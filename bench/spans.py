@@ -197,8 +197,8 @@ def traced_window(cell, seed: int, seconds: float, *,
     devs = run.devices_for(cell, require_tpu)
     peaks = peaks or peak_table.peaks_for(devs[0].device_kind)
     log = harness.CompileLog()
-    params = jax.block_until_ready(
-        model.make_params(cell.config, seed, device=devs[0]))
+    params = jax.block_until_ready(model.make_params(
+        cell.block, cell.config, seed, place=harness.placement(cell, devs)))
     h = harness.Harness(cell, params, seed)
     h.ramp(log)
     try:

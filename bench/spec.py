@@ -1,6 +1,6 @@
 """Resolve a workload name from ``BENCHMARK.json`` into the files that
 describe it.  Nothing here knows any particular cell: a new cell, traffic
-mix, configuration or metric is found by its name."""
+mix, configuration, block or metric is found by its name."""
 
 from __future__ import annotations
 
@@ -30,6 +30,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict                 # bench/configs/<config>.json
+    block: object                # bench/blocks/<config's "block">.py
     traffic_name: str
     traffic: dict                # bench/traffic/<traffic>.json
     cell: dict                   # bench/cells/<workload>.json
@@ -45,16 +46,29 @@ def _load_json(path: str) -> dict:
         raise SpecError(f"missing file {path}") from None
 
 
+def _load_module(path: str, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        f"{prefix}_{name.replace('.', '_').replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(root: str, name: str) -> Callable:
     """``bench/metrics/<name>.py``'s ``read(record)``."""
     path = os.path.join(root, "bench", "metrics", f"{name}.py")
     if not os.path.exists(path):
         raise SpecError(f"per-layer metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "bench_metric", name).read
+
+
+def load_block(root: str, name: str):
+    """``bench/blocks/<name>.py``: what the benchmark knows of one kind of
+    layer (its program configuration, weights, reference and work)."""
+    path = os.path.join(root, "bench", "blocks", f"{name}.py")
+    if not os.path.exists(path):
+        raise SpecError(f"block {name!r} has no module at {path}")
+    return _load_module(path, "bench_block", name)
 
 
 def _applies(metric: dict, workload: str, reported: Optional[set]) -> bool:
@@ -79,6 +93,10 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
     per = [m for m in bench["per_layer"]
            if _applies(m, workload, reported)]
 
+    config = _load_json(os.path.join(root, conf["file"]))
+    if "block" not in config:
+        raise SpecError(f"configuration {conf['file']} names no block")
+
     def metric(m: dict, per_layer: bool) -> Metric:
         return Metric(name=m["name"], unit=m["unit"],
                       reader=load_reader(root, m["name"]) if per_layer
@@ -86,7 +104,7 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
 
     return Cell(
         name=workload, chips=int(w["chips"]), config_name=w["config"],
-        config=_load_json(os.path.join(root, conf["file"])),
+        config=config, block=load_block(root, config["block"]),
         traffic_name=w["traffic"],
         traffic=_load_json(os.path.join(root, "bench", "traffic",
                                         f"{w['traffic']}.json")),

@@ -99,17 +99,38 @@ def clip(intervals: List[List[float]], lo: float, hi: float
             if e > lo and s < hi]
 
 
+def overlap(a: List[List[float]], b: List[List[float]]) -> float:
+    """Length of the intersection of two merged, sorted interval lists."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            total += hi - lo
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
 @dataclass
 class Reduction:
     window_ns: Tuple[float, float]
     devices: List[str]
-    busy_ns: Dict[str, float]                     # per device
+    busy_intervals: Dict[str, List[List[float]]]  # per device, merged
     programs: Dict[str, List[float]]              # name -> [ns, count]
     ops: Dict[str, float]                         # name -> ns (all devices)
     op_events: List[Event] = field(default_factory=list)
     module_events: List[Event] = field(default_factory=list)
     gaps: List[Tuple[str, float]] = field(default_factory=list)
     idle_by_span: Dict[str, float] = field(default_factory=dict)
+
+    @property
+    def busy_ns(self) -> Dict[str, float]:
+        """Busy nanoseconds per device."""
+        return {d: sum(e - s for s, e in iv)
+                for d, iv in self.busy_intervals.items()}
 
     @property
     def window_s(self) -> float:
@@ -138,14 +159,18 @@ class Reduction:
 
     def programs_running(self, match) -> List[str]:
         """Programs any of whose executions contain an operation that
-        ``match`` selects."""
-        ops = sorted((e.start_ns, e.end_ns) for e in self.op_events
-                     if match(e))
-        starts = [s for s, _ in ops]
+        ``match`` selects on the same device."""
+        ops: Dict[str, list] = defaultdict(list)
+        for e in self.op_events:
+            if match(e):
+                ops[e.plane].append((e.start_ns, e.end_ns))
+        for v in ops.values():
+            v.sort()
         out = set()
         for m in self.module_events:
-            i = bisect.bisect_left(starts, m.start_ns)
-            if i < len(ops) and ops[i][1] <= m.end_ns:
+            on = ops.get(m.plane, [])
+            i = bisect.bisect_left(on, (m.start_ns,))
+            if i < len(on) and on[i][1] <= m.end_ns:
                 out.add(m.name)
         return sorted(out)
 
@@ -187,7 +212,7 @@ def reduce(events: Sequence[Event], window_ns: Optional[Tuple] = None
     devices = sorted({e.plane for e in events
                       if e.plane.startswith(DEVICE_PREFIX)
                       and e.line == OPS_LINE})
-    busy: Dict[str, float] = {}
+    busy: Dict[str, List[List[float]]] = {}
     ops: Dict[str, float] = defaultdict(float)
     op_events: List[Event] = []
     gaps: List[Tuple[str, float]] = []
@@ -197,7 +222,7 @@ def reduce(events: Sequence[Event], window_ns: Optional[Tuple] = None
         evs = [e for e in events if e.plane == dev and e.line == OPS_LINE
                and e.end_ns > lo and e.start_ns < hi]
         merged = clip(union((e.start_ns, e.end_ns) for e in evs), lo, hi)
-        busy[dev] = sum(e - s for s, e in merged)
+        busy[dev] = merged
         for e in evs:
             name = op_name(e.name)
             if not name.startswith(CONTAINERS):
@@ -218,7 +243,8 @@ def reduce(events: Sequence[Event], window_ns: Optional[Tuple] = None
         programs[e.name][0] += e.dur_ns
         programs[e.name][1] += 1
     gaps.sort(key=lambda g: -g[1])
-    return Reduction(window_ns=(lo, hi), devices=devices, busy_ns=busy,
+    return Reduction(window_ns=(lo, hi), devices=devices,
+                     busy_intervals=busy,
                      programs=dict(programs), ops=dict(ops),
                      op_events=op_events, module_events=module_events,
                      gaps=gaps[:64],

@@ -17,7 +17,8 @@ if ROOT not in sys.path:
 # the smallest dense-GQA shapes that still route decode through the Pallas
 # paged kernel (head dim 128, page size 16)
 TINY = {
-    "name": "tiny-gqa", "source": "test", "hidden_size": 256,
+    "name": "tiny-gqa", "source": "test", "block": "dense_gqa",
+    "hidden_size": 256,
     "intermediate_size": 512, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 128, "num_hidden_layers": 2,
     "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -33,6 +34,17 @@ TRAFFIC = {
     "ramp_min_steps": 2}
 
 
+DEFAULT_LIMITS = {"gap": 0.05, "logprob": 0.05}
+
+
+def committed_limits() -> dict:
+    """The limits of every cell that ``BENCHMARK.json`` names."""
+    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    return {w["name"]: json.load(open(os.path.join(
+        ROOT, "bench", "cells", f"{w['name']}.json")))["check"]["limits"]
+        for w in bench["workloads"]}
+
+
 def engine(offload: bool) -> dict:
     return {"backend": "local", "num_microbatches": 2, "mb_size": 2,
             "page_size": 16, "n_local_pages": 12 if offload else 24,
@@ -43,7 +55,7 @@ def engine(offload: bool) -> dict:
 
 def make_root(tmp, *, limits=None, cells=None) -> str:
     """A checkout-like root whose BENCHMARK.json names tiny cells and
-    whose metric readers are the real ones."""
+    whose metric readers and blocks are the real ones."""
     root = str(tmp)
     real = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     cells = cells or {"tiny.decode-offload": True, "tiny.prefill": False}
@@ -51,14 +63,14 @@ def make_root(tmp, *, limits=None, cells=None) -> str:
     os.makedirs(os.path.join(root, "bench", "configs"), exist_ok=True)
     for d in ("cells", "traffic"):
         os.makedirs(os.path.join(root, "bench", d), exist_ok=True)
-    shutil.copytree(os.path.join(ROOT, "bench", "metrics"),
-                    os.path.join(root, "bench", "metrics"),
-                    dirs_exist_ok=True)
+    for d in ("metrics", "blocks"):
+        shutil.copytree(os.path.join(ROOT, "bench", d),
+                        os.path.join(root, "bench", d), dirs_exist_ok=True)
     json.dump(TINY, open(os.path.join(root, "bench", "configs",
                                       "tiny-gqa.json"), "w"))
     json.dump(TRAFFIC, open(os.path.join(root, "bench", "traffic",
                                          "short.json"), "w"))
-    limits = limits or {"gap": 0.05, "logprob": 0.05}
+    limits = limits or DEFAULT_LIMITS
     for name, offload in cells.items():
         json.dump({"engine": engine(offload),
                    "check": {"requests": 6, "limits": limits}},

@@ -5,13 +5,11 @@ program the window drives, and the rest of a run goes as usual on a tiny
 cell on the CPU.  The faults a one-chip serving cell can have: a step
 that returns its state unchanged, half of the batch left out (its rows
 answered from the other half), and a token altered where it is produced.
-(The exchange between chips has no place in a one-chip cell.)  Each
+(The exchange between chips is planted in the four-stage tick of
+``test_bench_pipeline.py``.)  Each
 fault is judged under the tiny cell's limits and under the limits of each
 cell that ``BENCHMARK.json`` commits.
 """
-
-import json
-import os
 
 import pytest
 
@@ -42,19 +40,9 @@ def _token_altered(orig):
     return fn
 
 
-def _committed_limits() -> dict:
-    """The limits of every cell that ``BENCHMARK.json`` names."""
-    bench = json.load(open(os.path.join(spec.ROOT, "BENCHMARK.json")))
-    out = {}
-    for w in bench["workloads"]:
-        path = os.path.join(spec.ROOT, "bench", "cells", f"{w['name']}.json")
-        out[w["name"]] = json.load(open(path))["check"]["limits"]
-    return out
-
-
 FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
           "token_altered": _token_altered}
-LIMITS = {"tiny": None, **_committed_limits()}
+LIMITS = {"tiny": None, **bench_cpu.committed_limits()}
 
 
 @pytest.fixture(scope="module")

@@ -1,6 +1,7 @@
-"""Operation and byte counts against hand-computed shapes, the table of
-peaks, and the refusals: an unknown device kind, a device that is not a
-TPU, and a checkout without the program."""
+"""Operation and byte counts of the dense-GQA block against hand-computed
+shapes, the roofline, the table of peaks, and the refusals: an unknown
+device kind, a device that is not a TPU, and a checkout without the
+program."""
 
 import json
 import os
@@ -11,10 +12,12 @@ import sys
 import pytest
 
 import bench_cpu  # noqa: F401  (the checkout root on sys.path)
-from bench import peaks, work
+from bench import peaks, spec, work
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+BLOCK = spec.load_block(spec.ROOT, "dense_gqa")
 
 # d 8, f 16, 2 q heads and 1 kv head of 4, 3 layers, vocab 10
 SMALL = {"hidden_size": 8, "intermediate_size": 16,
@@ -26,31 +29,30 @@ PER_LAYER = 64 + 32 + 32 + 64 + 384
 
 
 def test_matmul_params_per_layer():
-    from bench.model import matmul_params_per_layer
-    assert matmul_params_per_layer(SMALL) == PER_LAYER
+    assert BLOCK.matmul_params_per_layer(SMALL) == PER_LAYER
 
 
 def test_decode_token_flops():
     # 3 layers x (2 x 576 + 4 x 2 heads x 4 x ctx 5) + logits 2 x 8 x 10
-    assert work.decode_token_flops(SMALL, 5) == \
+    assert BLOCK.decode_token_flops(SMALL, 5) == \
         3 * (2 * PER_LAYER + 4 * 2 * 4 * 5) + 160
 
 
 def test_prefill_flops():
     # positions 2, 3, 4 attend over 3, 4, 5 positions: 12 in all
-    assert work.prefill_flops(SMALL, 2, 5, last=False) == \
+    assert BLOCK.prefill_flops(SMALL, 2, 5, last=False) == \
         3 * (2 * PER_LAYER * 3 + 4 * 2 * 4 * 12)
-    assert work.prefill_flops(SMALL, 2, 5, last=True) == \
-        work.prefill_flops(SMALL, 2, 5, last=False) + 160
+    assert BLOCK.prefill_flops(SMALL, 2, 5, last=True) == \
+        BLOCK.prefill_flops(SMALL, 2, 5, last=False) + 160
     # a whole prompt equals the sum of its chunks
-    whole = work.prefill_flops(SMALL, 0, 7, last=True)
-    parts = work.prefill_flops(SMALL, 0, 3, last=False) + \
-        work.prefill_flops(SMALL, 3, 7, last=True)
+    whole = BLOCK.prefill_flops(SMALL, 0, 7, last=True)
+    parts = BLOCK.prefill_flops(SMALL, 0, 3, last=False) + \
+        BLOCK.prefill_flops(SMALL, 3, 7, last=True)
     assert whole == parts
 
 
 def test_paged_decode_call():
-    flops, nbytes = work.paged_decode_call(SMALL, [5, 11])
+    flops, nbytes = BLOCK.paged_decode_call(SMALL, [5, 11])
     assert flops == 4 * 2 * 4 * 16
     # keys and values of 16 tokens, 1 kv head of 4, bf16; q and out of
     # 2 rows x 2 heads x 4
@@ -108,3 +110,10 @@ def test_cli_without_the_program_prints_no_result(tmp_path):
     out = _bench(str(tmp_path))
     assert out.returncode != 0
     assert out.stdout.strip() == ""
+
+
+def test_paged_decode_calls():
+    """One call a layer, each over the same rows."""
+    one = BLOCK.paged_decode_call(SMALL, [5, 11])
+    assert BLOCK.paged_decode_calls(SMALL, [5, 11], 3) == [one + (3,)]
+    assert BLOCK.paged_decode_calls(SMALL, [5, 11], 0) == []
